@@ -94,6 +94,9 @@ def main(argv=None) -> int:
             file=sys.stderr,
         )
         return 2
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     for name, fn in TARGETS.items():
         if args.only in (None, name):
             fn(quick)

@@ -1,7 +1,8 @@
 """Distributed RTAC: shard the constraint tensor over a (data, model) mesh.
 
-Runs on 8 emulated host devices (the same shard_map program runs unchanged on
-a real TPU mesh): constraint-tensor x-rows sharded over 'model', a batch of
+The mesh spans every device the process has: 8 emulated host devices on a
+CPU (set below), or the chips of a TPU host (the same shard_map program runs
+unchanged). Constraint-tensor x-rows are sharded over 'model', a batch of
 candidate domains (search nodes) over 'data'.
 
     PYTHONPATH=src python examples/distributed_ac.py
@@ -22,7 +23,9 @@ from repro.launch.mesh import make_mesh
 
 
 def main():
-    mesh = make_mesh((2, 4), ("data", "model"))
+    n_dev = jax.device_count()
+    n_data = 2 if n_dev % 2 == 0 and n_dev > 2 else 1
+    mesh = make_mesh((n_data, n_dev // n_data), ("data", "model"))
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"on {jax.device_count()} devices")
 

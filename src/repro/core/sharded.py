@@ -1,4 +1,4 @@
-"""Distributed RTAC — shard_map over the (data, model) production mesh.
+"""Distributed RTAC — shard_map over a (data, model) device mesh.
 
 Sharding story (DESIGN.md §2/§5): the constraint tensor is O(n²d²) and dominates
 memory, so its *x*-rows are sharded over the ``model`` axis — each model shard
@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .rtac import EnforceResult
 
@@ -108,7 +107,7 @@ def make_sharded_enforcer(
     batch_spec = P(batch_axes)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(model_axis),  # cons x-rows
@@ -117,7 +116,7 @@ def make_sharded_enforcer(
             batch_spec,  # changed batch
         ),
         out_specs=EnforceResult(batch_spec, batch_spec, batch_spec),
-        check_rep=False,
+        check_vma=False,
     )
     def _sharded(cons_blk, mask_blk, dom_b, changed_b):
         fn = functools.partial(

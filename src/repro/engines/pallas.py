@@ -1,28 +1,31 @@
-"""Pallas kernel engines — dense uint8 and bitpacked uint32 revise (DESIGN.md §4).
+"""Pallas kernel engines — dense int8 and bitpacked int32 encodings (DESIGN.md §4).
 
 ``prepare`` pays the O(n²d²) padding / transpose / bitpack of the constraint
 tensor exactly once per CSP; the hot path pads only the O(n·d) domain (and
 changed seed) into kernel coordinates and un-pads the result, so callers never
-see padded shapes. The revise closures come from the ``lru_cache``-d factories
-in `repro.kernels.ops`, so their identity is stable and the RTAC fixpoint
-compiles once per (shape, blocks) — including under ``vmap`` for
-``enforce_batch`` (Pallas interpret and compiled modes both batch).
+see padded shapes. The closures come from the ``lru_cache``-d factories in
+`repro.kernels.ops`, keyed on (encoding, interpret), so their identity is
+stable and each jitted program compiles once per shape — including under
+``vmap`` for ``enforce_batch``.
+
+Whether the kernels run interpreted is decided when an engine is constructed,
+from the backend (`ops.interpret_mode`): the CPU interprets, a TPU compiles.
 
 Workload/service paths are fully device-resident (no host routing):
 
-- ``prepare_many`` stacks the per-instance prepared networks into
-  ``(B, n_p·d_p, cols)`` tables (packed uint32 words for `pallas_packed`) and
-  ``enforce_many`` runs ONE stacked fixpoint (`rtac.enforce_rows_generic`)
-  whose revise is the stacked kernel — the grid carries the instance axis.
+- ``prepare_many`` stacks the per-instance prepared networks into tables
+  (``(B, N, N)`` int8 for `pallas_dense`, ``(B, W, n_p, N)`` int32 words for
+  `pallas_packed`, N = n_p·d_p) and ``enforce_many`` runs ONE stacked
+  fixpoint — the fused kernel, or the stepped while_loop around the stacked
+  revise kernel.
 - ``open_slot_pool`` backs the service with a `StackedSlotPool` over the same
-  tables: installs are donated ``.at[slot].set`` row writes into the
-  ``(C, n_p·d_p, n_p·W)`` packed slot table, and every round is one jitted
-  gather + stacked-kernel dispatch. Results are bit-identical to the einsum
+  tables: installs are donated ``.at[slot].set`` row writes, and every round is
+  one jitted gather + kernel dispatch. Results are bit-identical to the einsum
   slot path by construction (same coroutine, same per-row fixpoint semantics).
 
-``network_nbytes`` reports the engine's TRUE resident footprint — padded u8
-bytes for `pallas_dense`, packed u32 words (8× less) for `pallas_packed` — so
-the service cache budget admits proportionally more packed networks.
+``network_nbytes`` reports the engine's TRUE resident footprint — padded int8
+bytes for `pallas_dense`, packed words (8× less) for `pallas_packed` — so the
+service cache budget admits proportionally more packed networks.
 """
 
 from __future__ import annotations
@@ -41,50 +44,36 @@ from repro.core.engine import (
     as_changed,
     pad_changed,
     pad_dom,
-    padded_shape,
     resolve_instance_idx,
 )
 from repro.core.rtac import EnforceResult, enforce_batch_generic, enforce_generic
-from repro.kernels import ops
+from repro.kernels import autotune, ops
 from . import register
 
 
 class _PallasEngine(Engine):
-    """Shared prepare/enforce plumbing; subclasses pick the kernel binding.
+    """Shared prepare/enforce plumbing; subclasses pick the encoding
+    (``kind``: "dense" int8 or "packed" int32 words).
 
-    Subclass hooks (``dims`` is the kernel-coordinate tuple — (n_p, d_p) for
-    dense, (n_p, d_p, w) for packed):
-
-    - ``_prepare_net(csp) -> (network, dims)`` — the memoized padded/packed
-      resident form;
-    - ``_dims(n, d)`` — kernel dims for a caller shape (no CSP needed; must
-      agree with ``_prepare_net`` for that shape);
-    - ``_revise_fn(dims)`` / ``_rows_fn(dims)`` — the single and stacked
-      revise closures;
-    - ``_empty_tables(dims, capacity)`` — zeroed slot tables for the pool.
+    ``dims`` is the kernel-coordinate tuple — (n_p, d_p) for dense,
+    (n_p, d_p, w) for packed (`ops.kernel_dims`).
     """
 
+    kind: str
     stacked_many = True
     slot_table = True
     device_frontier = True
     # stacked kernel rows are near-free up to the tile width
     speculative_rows_hint = 64
 
-    def __init__(
-        self,
-        block_rx: int = 8,
-        block_ry: int = 8,
-        interpret: bool = True,
-        fixpoint: str | None = None,
-    ):
-        self.block_rx = block_rx
-        self.block_ry = block_ry
-        self.interpret = interpret
+    def __init__(self, fixpoint: str | None = None):
+        # decided once, from the backend: interpreted on CPU, compiled on TPU
+        self.interpret = ops.interpret_mode()
         # Recurrence placement: "fused" runs the whole fixpoint inside ONE
-        # kernel launch (domains pinned in VMEM, SMEM convergence flag);
-        # "stepped" is the original XLA while_loop around per-iteration revise
-        # launches — kept as the fallback and the parity oracle. Bit-identical
-        # by construction (tests/test_fused.py sweeps both).
+        # kernel launch (domains resident in VMEM); "stepped" is the XLA
+        # while_loop around per-iteration revise launches — kept as the
+        # fallback and the parity oracle. Bit-identical by construction
+        # (tests/test_fused.py sweeps both).
         if fixpoint is None:
             fixpoint = os.environ.get("REPRO_PALLAS_FIXPOINT", "fused")
         if fixpoint not in ("fused", "stepped"):
@@ -94,17 +83,25 @@ class _PallasEngine(Engine):
         self.fixpoint = fixpoint
         self.fused_fixpoint = fixpoint == "fused"
 
-    def _pad_shape(self, n: int, d: int):
-        """The §2 padding the kernel shims apply for this engine's blocks —
-        same `padded_shape` formula, same `ops.D_MULT`, agreement by
-        construction."""
-        return padded_shape(n, d, max(self.block_rx, self.block_ry), ops.D_MULT)
+    def stepped(self) -> "_PallasEngine":
+        """This engine with the stepped fixpoint — same encoding and
+        interpret mode (the service ladder's middle rung)."""
+        sibling = type(self)(fixpoint="stepped")
+        sibling.interpret = self.interpret
+        return sibling
+
+    def _dims(self, n: int, d: int):
+        return ops.kernel_dims(self.kind, n, d)
+
+    def _prepare_net(self, csp: CSP):
+        network, _, dims = ops.prepare_network(self.kind, csp)
+        return network, dims
 
     # --- single-network path (one search, many domains) ---------------------
 
     def _prepare_payload(self, csp: CSP):
         network, dims = self._prepare_net(csp)
-        return network, dims, self._revise_fn(dims)
+        return network, dims, ops.revise_fn(self.kind, self.interpret)
 
     def enforce(self, prepared: PreparedNetwork, dom, changed0=None) -> EnforceResult:
         network, dims, revise_fn = prepared.payload
@@ -129,14 +126,13 @@ class _PallasEngine(Engine):
 
     def _prepare_many_payload(self, csps):
         nets = [self._prepare_net(c) for c in csps]
-        dims = nets[0][1]
         tables = (
             jnp.stack([net[0][0] for net in nets]),
             jnp.stack([net[0][1] for net in nets]),
         )
-        return tables, dims, self._rows_fn(dims)
+        return tables, nets[0][1]
 
-    def _rows_dispatch(self, tables, dims, rows_fn, n, d, doms, changed0, idx):
+    def _rows_dispatch(self, tables, dims, n, d, doms, changed0, idx):
         """Pad R caller-coordinate rows into kernel coordinates, run the ONE
         stacked gather+kernel fixpoint, un-pad. Shared by `enforce_many` and
         the slot pool."""
@@ -145,46 +141,34 @@ class _PallasEngine(Engine):
         dom_p = pad_dom(doms, n_p, d_p)
         ch_p = pad_changed(as_changed(changed0), n, n_p, batch=doms.shape[:-2])
         if self.fused_fixpoint:
-            self._maybe_autotune(dims, dom_p.shape[0])
+            autotune.maybe_tune(self.kind, n_p, d_p, dom_p.shape[0])
             res = ops.enforce_rows_fused(
                 tables, dom_p, ch_p, jnp.asarray(idx),
-                fixpoint_rows_fn=self._fixpoint_rows_fn(dims),
+                fixpoint_rows_fn=ops.fixpoint_rows_fn(self.kind, self.interpret),
             )
         else:
             res = rtac.enforce_rows_generic(
-                tables, dom_p, ch_p, jnp.asarray(idx), revise_rows_fn=rows_fn
+                tables, dom_p, ch_p, jnp.asarray(idx),
+                revise_rows_fn=ops.rows_fn(self.kind, self.interpret),
             )
         return EnforceResult(res.dom[:, :n, :d], res.consistent, res.n_recurrences)
-
-    def _maybe_autotune(self, dims, r: int) -> None:
-        """Eager, env-gated (``REPRO_AUTOTUNE=1``) tune-on-first-use for the
-        bucket about to be dispatched — runs BEFORE the jitted fused program
-        traces, so the schedule it bakes is the tuned one."""
-        from repro.kernels import autotune
-
-        w = dims[2] if len(dims) > 2 else 0
-        autotune.maybe_tune(self._fixpoint_kind, dims[0], dims[1], w, r)
 
     def enforce_many(
         self, prepared: PreparedMany, doms, changed0=None, instance_idx=None
     ) -> EnforceResult:
-        tables, dims, rows_fn = prepared.payload
+        tables, dims = prepared.payload
         idx = resolve_instance_idx(
             instance_idx, prepared.n_instances, len(doms)
         )
         return self._rows_dispatch(
-            tables, dims, rows_fn,
-            prepared.n_vars, prepared.dom_size, doms, changed0, idx,
+            tables, dims, prepared.n_vars, prepared.dom_size, doms, changed0, idx
         )
 
     def _open_stacked_slot_pool(self, n_vars, dom_size, capacity) -> StackedSlotPool:
         dims = self._dims(n_vars, dom_size)
-        rows_fn = self._rows_fn(dims)
 
         def dispatch(tables, doms, changed0, idx):
-            return self._rows_dispatch(
-                tables, dims, rows_fn, n_vars, dom_size, doms, changed0, idx
-            )
+            return self._rows_dispatch(tables, dims, n_vars, dom_size, doms, changed0, idx)
 
         return StackedSlotPool(
             self, n_vars, dom_size, capacity,
@@ -196,13 +180,12 @@ class _PallasEngine(Engine):
     # --- device-resident frontiers (DESIGN.md §8) ---------------------------
 
     def frontier_fix(self):
-        """The `lru_cache`-d fused assign+revise entry from `kernels.ops`
-        (stable identity per (kernel, blocks, interpret) — keys the frontier
-        step's jit cache); kernel dims derive from the row shapes at trace
-        time, so one fix object serves every bucket. In fused mode the whole
-        round's recurrence is one kernel launch."""
-        fn = self._frontier_fused_fn if self.fused_fixpoint else self._frontier_fn
-        return fn(self.block_rx, self.block_ry, self.interpret)
+        """The `lru_cache`-d fused assign+enforce entry from `kernels.ops`
+        (stable identity per (encoding, fixpoint, interpret) — keys
+        the frontier step's jit cache); kernel dims derive from the row shapes
+        at trace time, so one fix object serves every bucket. In fused mode
+        the whole round's recurrence is one kernel launch."""
+        return ops.frontier_fn(self.kind, self.fused_fixpoint, self.interpret)
 
     def frontier_networks(self, prepared: PreparedMany):
         return prepared.payload[0]
@@ -210,89 +193,38 @@ class _PallasEngine(Engine):
 
 @register
 class PallasDenseEngine(_PallasEngine):
-    """Incremental RTAC with the dense uint8 Pallas revise kernel."""
+    """Incremental RTAC with the dense int8 Pallas kernels (MXU support count)."""
 
     name = "pallas_dense"
-    _frontier_fn = staticmethod(ops._dense_frontier_fn)
-    _frontier_fused_fn = staticmethod(ops._dense_frontier_fused_fn)
-    _fixpoint_kind = "dense"
-
-    def _prepare_net(self, csp: CSP):
-        network, _, (n_p, d_p) = ops.prepare_dense(csp, self.block_rx, self.block_ry)
-        return network, (n_p, d_p)
-
-    def _dims(self, n: int, d: int):
-        return self._pad_shape(n, d)
-
-    def _revise_fn(self, dims):
-        n_p, d_p = dims
-        return ops._dense_revise_fn(n_p, d_p, self.block_rx, self.block_ry, self.interpret)
-
-    def _rows_fn(self, dims):
-        n_p, d_p = dims
-        return ops._dense_rows_fn(n_p, d_p, self.block_rx, self.block_ry, self.interpret)
-
-    def _fixpoint_rows_fn(self, dims):
-        n_p, d_p = dims
-        return ops._dense_fixpoint_rows_fn(
-            n_p, d_p, self.block_rx, self.block_ry, self.interpret
-        )
+    kind = "dense"
 
     def _empty_tables(self, dims, capacity: int):
         n_p, d_p = dims
         return (
-            jnp.zeros((capacity, n_p * d_p, n_p * d_p), jnp.uint8),
+            jnp.zeros((capacity, n_p * d_p, n_p * d_p), jnp.int8),
             jnp.zeros((capacity, n_p, n_p), jnp.uint8),
         )
 
     def network_nbytes(self, n_vars: int, dom_size: int) -> int:
-        n_p, d_p = self._pad_shape(n_vars, dom_size)
-        return n_p * d_p * n_p * d_p + n_p * n_p  # u8 cons2 + u8 mask
+        n_p, d_p = self._dims(n_vars, dom_size)
+        return n_p * d_p * n_p * d_p + n_p * n_p  # int8 cons + u8 mask
 
 
 @register
 class PallasPackedEngine(_PallasEngine):
-    """Incremental RTAC with the bitpacked uint32 Pallas revise kernel
-    (8× less constraint traffic than uint8, 16× than bf16)."""
+    """Incremental RTAC with the bitpacked Pallas kernels (32 values per
+    word: 8× less constraint traffic than int8, 16× than bf16)."""
 
     name = "pallas_packed"
-    _frontier_fn = staticmethod(ops._packed_frontier_fn)
-    _frontier_fused_fn = staticmethod(ops._packed_frontier_fused_fn)
-    _fixpoint_kind = "packed"
-
-    def _prepare_net(self, csp: CSP):
-        network, _, (n_p, d_p, w) = ops.prepare_packed(csp, self.block_rx, self.block_ry)
-        return network, (n_p, d_p, w)
-
-    def _dims(self, n: int, d: int):
-        n_p, d_p = self._pad_shape(n, d)
-        return n_p, d_p, -(-d_p // 32)
-
-    def _revise_fn(self, dims):
-        n_p, d_p, w = dims
-        return ops._packed_revise_fn(
-            n_p, d_p, w, self.block_rx, self.block_ry, self.interpret
-        )
-
-    def _rows_fn(self, dims):
-        n_p, d_p, w = dims
-        return ops._packed_rows_fn(
-            n_p, d_p, w, self.block_rx, self.block_ry, self.interpret
-        )
-
-    def _fixpoint_rows_fn(self, dims):
-        n_p, d_p, w = dims
-        return ops._packed_fixpoint_rows_fn(
-            n_p, d_p, w, self.block_rx, self.block_ry, self.interpret
-        )
+    kind = "packed"
 
     def _empty_tables(self, dims, capacity: int):
         n_p, d_p, w = dims
         return (
-            jnp.zeros((capacity, n_p * d_p, n_p * w), jnp.uint32),
+            jnp.zeros((capacity, w, n_p, n_p * d_p), jnp.int32),
             jnp.zeros((capacity, n_p, n_p), jnp.uint8),
         )
 
     def network_nbytes(self, n_vars: int, dom_size: int) -> int:
         n_p, d_p, w = self._dims(n_vars, dom_size)
-        return n_p * d_p * n_p * w * 4 + n_p * n_p  # u32 packed words + u8 mask
+        return n_p * d_p * n_p * w * 4 + n_p * n_p  # int32 packed words + u8 mask

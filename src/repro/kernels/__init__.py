@@ -1,11 +1,13 @@
 """Pallas TPU kernels for the paper's compute hot spot (the revise contraction).
 
-rtac_support   dense uint8 fused support-count+clamp+AND-reduce (VPU streaming)
-bitpack_support  uint32 bitpacked variant (beyond paper: 16x less traffic)
-ops            jit'd wrappers + padding/packing + prepare_* network builders
+rtac_support   revise sweep + in-kernel fixpoint, dense int8 (MXU) or
+               bitpacked int32 words (VPU) — one body, the encoding a parameter
+ops            jit'd wrappers + padding/packing + network builders, and the
+               one place the interpret/compile decision is made
+autotune       per-bucket instance tiling (block_r) under a VMEM budget
 ref            pure-jnp oracles the kernels are validated against
 """
 
-from . import bitpack_support, ops, ref, rtac_support
+from . import ops, ref, rtac_support
 
-__all__ = ["bitpack_support", "ops", "ref", "rtac_support"]
+__all__ = ["ops", "ref", "rtac_support"]

@@ -1,34 +1,35 @@
-"""Autotuned block shapes for the fused fixpoint kernels (DESIGN.md §4).
+"""Autotuned instance tiling for the fused fixpoint kernels (DESIGN.md §4).
 
-The fused kernels expose a *schedule* — instance-axis tiling ``block_r``,
-revise-sweep tiles ``block_rx``/``block_ry``, and the in-kernel loop-nest
-order ``sweep`` ("xy" / "yx") — that never changes results (every candidate
-is a Jacobi sweep OR-ing into one violated accumulator against the pre-sweep
-domain), only VMEM access order and grid shape. This module picks the fastest
-schedule per shape bucket, once, and persists the choice.
+The fused kernel's schedule is ``block_r``, the rows one grid cell runs to
+their fixpoint together. It never changes results (every row's recurrence is
+independent and frozen once inactive), only the grid length and how much of
+VMEM a cell holds. This module picks the fastest ``block_r`` per shape bucket,
+once, and persists the choice.
 
 Mechanics:
 
-- Buckets are ``kind/n{n_p}/d{d_p}/w{W}/r{pow2(R)}`` — padded kernel dims are
-  already quantized, and the round width R is pow2-bucketed exactly like the
-  frontier's ratcheted widths, so a handful of buckets covers a run.
+- Buckets are ``device_kind/kind/n{n_p}/d{d_p}/r{pow2(R)}``. The device kind
+  keeps a schedule timed on one chip (or on the CPU interpreter) from ever
+  being applied on another; padded kernel dims are already quantized, and the
+  round width R is pow2-bucketed exactly like the frontier's ratcheted widths.
+- Candidates are the powers of two up to 8 whose cell fits the kernels' VMEM
+  budget (`rtac_support.max_block_r`), so every candidate compiles.
 - ``tune``/``ensure_tuned`` time each candidate EAGERLY (block_until_ready on
   a seeded synthetic workload of real `random_csp` networks at the bucket
-  shape) and store the winner. Timing never happens at jit-trace time.
+  shape), in the interpret mode of the backend, and store the winner. Timing
+  never happens at jit-trace time.
 - The winners persist in a versioned JSON cache (``REPRO_AUTOTUNE_CACHE``
-  overrides the path). ``get_config`` — the only call sites of which are the
-  trace-time schedule lookups in `kernels.ops` — READS the in-memory table
-  (loaded from disk once) and falls back to defaults for untuned buckets; it
-  never times anything. Tune before first dispatch of a shape (the jitted
-  program bakes the schedule it saw): the benchmarks and the CI smoke invoke
-  ``python -m repro.kernels.autotune`` explicitly, and engines opt in via the
-  ``REPRO_AUTOTUNE=1`` environment gate.
+  overrides the path). ``get_config`` — read at trace time by
+  `fused_block_r` — READS the in-memory table (loaded from disk once) and
+  falls back to the budget's largest ``block_r`` for untuned buckets; it never
+  times anything. Tune before first dispatch of a shape (the jitted program
+  bakes the schedule it saw): run ``python -m repro.kernels.autotune``, or set
+  ``REPRO_AUTOTUNE=1`` to tune on first use.
 
-Cache format (``repro-autotune/v1``)::
+Cache format (``repro-autotune/v2``)::
 
-    {"schema": "repro-autotune/v1",
-     "configs": {"packed/n16/d8/w1/r8":
-                 {"block_r": 8, "block_rx": 8, "block_ry": 8, "sweep": "xy"}}}
+    {"schema": "repro-autotune/v2",
+     "configs": {"TPU v5 lite/packed/n64/d24/r64": {"block_r": 8}}}
 """
 
 from __future__ import annotations
@@ -44,33 +45,23 @@ from typing import Dict, List, Optional
 from repro import obs
 from repro.core.engine import next_pow2
 
-SCHEMA = "repro-autotune/v1"
+SCHEMA = "repro-autotune/v2"
 CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 TUNE_ENV = "REPRO_AUTOTUNE"
-SWEEPS = ("xy", "yx")
 
 
 @dataclasses.dataclass(frozen=True)
 class TuneConfig:
-    """One fused-kernel schedule. Every field is parity-neutral by
-    construction (see module docstring) — tuning can never change results."""
+    """One fused-kernel schedule; parity-neutral by construction."""
 
     block_r: int
-    block_rx: int
-    block_ry: int
-    sweep: str
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
 
     @classmethod
     def from_dict(cls, d: dict) -> "TuneConfig":
-        return cls(
-            block_r=int(d["block_r"]),
-            block_rx=int(d["block_rx"]),
-            block_ry=int(d["block_ry"]),
-            sweep=str(d["sweep"]),
-        )
+        return cls(block_r=int(d["block_r"]))
 
 
 #: in-memory config table, keyed by bucket string; populated by `load_cache`
@@ -86,11 +77,17 @@ def cache_path() -> Path:
     return Path.home() / ".cache" / "repro" / "autotune.json"
 
 
-def bucket_key(kind: str, n_p: int, d_p: int, w: int, r: int) -> str:
-    """Bucket id: kernel dims are already padded/quantized; the row count R is
-    pow2-bucketed (the same quantization the frontier's ratcheted widths and
-    the service's round padding apply)."""
-    return f"{kind}/n{n_p}/d{d_p}/w{w}/r{next_pow2(max(int(r), 1))}"
+def device_kind() -> str:
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
+def bucket_key(kind: str, n_p: int, d_p: int, r: int) -> str:
+    """Bucket id on this process's device: kernel dims are already
+    padded/quantized; the row count R is pow2-bucketed (the same quantization
+    the frontier's ratcheted widths and the service's round padding apply)."""
+    return f"{device_kind()}/{kind}/n{n_p}/d{d_p}/r{next_pow2(max(int(r), 1))}"
 
 
 def load_cache(path: Optional[Path] = None, force: bool = False) -> int:
@@ -140,26 +137,32 @@ def effective_block_r(block_r: int, r: int) -> int:
     return br
 
 
-def _sanitize(cfg: TuneConfig, n_p: int, block_rx: int, block_ry: int) -> TuneConfig:
-    """A cached schedule must still tile this shape (the cache may predate a
-    layout change): sweep tiles must divide n_p, else fall back per-field."""
-    brx = cfg.block_rx if n_p % cfg.block_rx == 0 else block_rx
-    bry = cfg.block_ry if n_p % cfg.block_ry == 0 else block_ry
-    sweep = cfg.sweep if cfg.sweep in SWEEPS else "xy"
-    return TuneConfig(max(1, cfg.block_r), brx, bry, sweep)
+def _budget_block_r(kind: str, n_p: int, d_p: int) -> int:
+    from repro.kernels import rtac_support
+
+    return max(1, rtac_support.max_block_r(kind, n_p, d_p))
 
 
-def get_config(
-    kind: str, n_p: int, d_p: int, w: int, r: int, block_rx: int, block_ry: int
-) -> TuneConfig:
+def _sanitize(cfg: TuneConfig, kind: str, n_p: int, d_p: int) -> TuneConfig:
+    """A cached schedule must still fit this shape's VMEM budget (the cache
+    may predate a budget or layout change): clamp ``block_r`` to it."""
+    return TuneConfig(max(1, min(cfg.block_r, _budget_block_r(kind, n_p, d_p))))
+
+
+def get_config(kind: str, n_p: int, d_p: int, r: int) -> TuneConfig:
     """Trace-time schedule lookup — a pure read. Untuned buckets get the
-    engine defaults (block_r=8, the engine's sweep tiles, "xy")."""
+    largest ``block_r`` the VMEM budget allows."""
     if _LOADED is None:
         load_cache()
-    cfg = _CONFIGS.get(bucket_key(kind, n_p, d_p, w, r))
+    cfg = _CONFIGS.get(bucket_key(kind, n_p, d_p, r))
     if cfg is None:
-        return TuneConfig(8, block_rx, block_ry, "xy")
-    return _sanitize(cfg, n_p, block_rx, block_ry)
+        return TuneConfig(_budget_block_r(kind, n_p, d_p))
+    return _sanitize(cfg, kind, n_p, d_p)
+
+
+def fused_block_r(kind: str, n_p: int, d_p: int, r: int) -> int:
+    """The ``block_r`` a fused launch of R rows runs with (``block_r | R``)."""
+    return effective_block_r(get_config(kind, n_p, d_p, r).block_r, r)
 
 
 # ---------------------------------------------------------------------------
@@ -167,81 +170,51 @@ def get_config(
 # ---------------------------------------------------------------------------
 
 
-def candidate_configs(n_p: int, r: int) -> List[TuneConfig]:
-    """A deliberately small grid: ≤ 2 instance tilings × ≤ 2 sweep-tile sizes
-    per axis × both sweep orders (≤ 16 kernels per bucket)."""
-    tiles = [v for v in (8, 16) if n_p % v == 0] or [n_p]
-    tiles = tiles[-2:]
-    row_tiles = sorted({effective_block_r(v, r) for v in (1, 8)})
-    return [
-        TuneConfig(br, brx, bry, sweep)
-        for br in row_tiles
-        for brx in tiles
-        for bry in tiles
-        for sweep in SWEEPS
-    ]
+def candidate_configs(kind: str, n_p: int, d_p: int, r: int) -> List[TuneConfig]:
+    """Every distinct effective ``block_r`` among the powers of two up to
+    the VMEM budget's largest — each one a schedule that compiles."""
+    cap = _budget_block_r(kind, n_p, d_p)
+    tiles = sorted({effective_block_r(v, r) for v in (1, 2, 4, 8) if v <= cap})
+    return [TuneConfig(br) for br in tiles]
 
 
-def _tune_workload(kind: str, n_p: int, d_p: int, r: int, interpret: bool):
+def _tune_workload(kind: str, n_p: int, d_p: int, r: int):
     """A seeded synthetic bucket workload: 3 real `random_csp` networks at
     exactly the padded shape (n_p, d_p are tile multiples, so preparation is
-    shape-preserving), r root rows round-robined across them."""
+    shape-preserving), r root rows round-robined across them, as kernel
+    operands."""
     import jax.numpy as jnp
     import numpy as np
 
     from repro.core import random_csp
-    from repro.core.engine import pad_changed, pad_dom
+    from repro.core.engine import pad_changed
     from repro.kernels import ops
 
     csps = [random_csp(n_p, d_p, 0.6, 0.5, seed=1000 + i) for i in range(3)]
-    prepare = ops.prepare_packed if kind == "packed" else ops.prepare_dense
-    prepared = [prepare(c, 8, 8) for c in csps]
-    dims = prepared[0][2]
-    if (dims[0], dims[1]) != (n_p, d_p):  # pragma: no cover - guarded by callers
-        raise ValueError(f"bucket ({n_p}, {d_p}) is not a padded shape: got {dims}")
+    prepared = [ops.prepare_network(kind, c) for c in csps]
+    if prepared[0][2][:2] != (n_p, d_p):  # pragma: no cover - guarded by callers
+        raise ValueError(f"bucket ({n_p}, {d_p}) is not a padded shape: got {prepared[0][2]}")
     idx = np.arange(r, dtype=np.int32) % len(csps)
-    cons_g = jnp.stack([prepared[j][0][0] for j in idx])
-    mask_g = jnp.stack([prepared[j][0][1] for j in idx])
+    net_g = (
+        jnp.stack([prepared[j][0][0] for j in idx]),
+        jnp.stack([prepared[j][0][1] for j in idx]),
+    )
     doms = jnp.stack([prepared[j][1] for j in idx])
-    changed = pad_changed(None, n_p, n_p, batch=(r,))
-    return dims, (cons_g, mask_g), pad_dom(doms, n_p, d_p), changed
+    return ops.kernel_operands(net_g, doms, pad_changed(None, n_p, n_p, batch=(r,)))
 
 
-def _time_candidate(
-    kind: str, dims, net_g, dom_p, ch_p, cfg: TuneConfig,
-    interpret: bool, repeats: int,
-) -> float:
+def _time_candidate(kind: str, d_p: int, operands, cfg: TuneConfig,
+                    interpret: bool, repeats: int) -> float:
     import jax
-    import jax.numpy as jnp
 
-    from repro.kernels import bitpack_support, ref, rtac_support
+    from repro.kernels import rtac_support
 
-    r = dom_p.shape[0]
-    br = effective_block_r(cfg.block_r, r)
-    cons_g, mask_g = net_g
+    block_r = effective_block_r(cfg.block_r, operands[1].shape[0])
 
-    if kind == "packed":
-        n_p, d_p, w = dims
-        dom_pk = ref.pack_bits_ref(dom_p).reshape(r, 1, n_p * w)
-
-        def run():
-            return bitpack_support.packed_fixpoint_stacked(
-                cons_g, dom_pk,
-                ch_p.astype(jnp.uint8).reshape(r, 1, n_p), mask_g,
-                d=d_p, w=w, block_r=br, block_rx=cfg.block_rx,
-                block_ry=cfg.block_ry, sweep=cfg.sweep, interpret=interpret,
-            )
-    else:
-        n_p, d_p = dims[0], dims[1]
-
-        def run():
-            return rtac_support.dense_fixpoint_stacked(
-                cons_g,
-                dom_p.astype(jnp.uint8).reshape(r, 1, n_p * d_p),
-                ch_p.astype(jnp.uint8).reshape(r, 1, n_p), mask_g,
-                d=d_p, block_r=br, block_rx=cfg.block_rx,
-                block_ry=cfg.block_ry, sweep=cfg.sweep, interpret=interpret,
-            )
+    def run():
+        return rtac_support.fixpoint_rows(
+            *operands, encoding=kind, d=d_p, block_r=block_r, interpret=interpret
+        )
 
     jax.block_until_ready(run())  # compile/warm outside the timed window
     best = float("inf")
@@ -258,60 +231,60 @@ def tune(
     d_p: int,
     r: int = 8,
     *,
-    interpret: bool = True,
     repeats: int = 2,
     save: bool = True,
     path: Optional[Path] = None,
 ) -> TuneConfig:
     """Time every candidate schedule for one bucket (eagerly — never call from
-    a traced context), record the winner, persist the cache. Returns it."""
+    a traced context) in the backend's interpret mode, record the winner,
+    persist the cache. Returns it."""
+    from repro.kernels import ops
+
     if kind not in ("dense", "packed"):
         raise ValueError(f"unknown kernel kind {kind!r}")
+    interpret = ops.interpret_mode()
     r = next_pow2(max(int(r), 1))
     t_search0 = time.perf_counter()
     with obs.span("autotune.search", cat="autotune", kind=kind,
                   n=n_p, d=d_p, r=r) as _sp:
-        dims, net_g, dom_p, ch_p = _tune_workload(kind, n_p, d_p, r, interpret)
-        w = dims[2] if kind == "packed" else 0
+        operands = _tune_workload(kind, n_p, d_p, r)
         best_cfg, best_t = None, float("inf")
-        candidates = candidate_configs(n_p, r)
+        candidates = candidate_configs(kind, n_p, d_p, r)
         for cfg in candidates:
-            t = _time_candidate(kind, dims, net_g, dom_p, ch_p, cfg, interpret, repeats)
+            t = _time_candidate(kind, d_p, operands, cfg, interpret, repeats)
             if t < best_t:
                 best_cfg, best_t = cfg, t
         if _sp is not None:
             _sp.args["candidates"] = len(candidates)
     obs.counter_add("autotune.tuned_buckets")
     obs.observe("autotune.search_seconds", time.perf_counter() - t_search0)
-    _CONFIGS[bucket_key(kind, n_p, d_p, w, r)] = best_cfg
+    _CONFIGS[bucket_key(kind, n_p, d_p, r)] = best_cfg
     if save:
         save_cache(path)
     return best_cfg
 
 
-def ensure_tuned(
-    kind: str, n_p: int, d_p: int, w: int, r: int, **tune_kwargs
-) -> TuneConfig:
+def ensure_tuned(kind: str, n_p: int, d_p: int, r: int, **tune_kwargs) -> TuneConfig:
     """Tune the bucket only if the (loaded) cache has no entry for it."""
     if _LOADED is None:
         load_cache(tune_kwargs.get("path"))
-    hit = _CONFIGS.get(bucket_key(kind, n_p, d_p, w, r))
+    hit = _CONFIGS.get(bucket_key(kind, n_p, d_p, r))
     if hit is not None:
         return hit
     return tune(kind, n_p, d_p, r, **tune_kwargs)
 
 
-def maybe_tune(kind: str, n_p: int, d_p: int, w: int, r: int) -> Optional[TuneConfig]:
+def maybe_tune(kind: str, n_p: int, d_p: int, r: int) -> Optional[TuneConfig]:
     """Engine hook: tune-on-first-use, gated by ``REPRO_AUTOTUNE=1`` (timing
     a bucket in interpret mode is not free, so it is opt-in)."""
     if not os.environ.get(TUNE_ENV):
         return None
-    return ensure_tuned(kind, n_p, d_p, w, r)
+    return ensure_tuned(kind, n_p, d_p, r)
 
 
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
-        description="Tune fused-fixpoint block shapes for one bucket"
+        description="Tune the fused fixpoint's block_r for one bucket"
     )
     ap.add_argument("--kind", choices=("dense", "packed"), default="packed")
     ap.add_argument("--n", type=int, default=16, help="padded var count n_p")
@@ -327,8 +300,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     load_cache(args.cache)
     cfg = tune(args.kind, args.n, args.d, args.rows,
                repeats=args.repeats, path=args.cache)
-    w = -(-args.d // 32) if args.kind == "packed" else 0
-    key = bucket_key(args.kind, args.n, args.d, w, args.rows)
+    key = bucket_key(args.kind, args.n, args.d, args.rows)
     print(json.dumps({"bucket": key, "config": cfg.to_dict(),
                       "cache": str(args.cache or cache_path())}))
     return 0

@@ -1,28 +1,29 @@
-"""Jitted wrappers binding the Pallas revise kernels into the RTAC fixpoint.
+"""Jitted wrappers binding the Pallas kernels into the RTAC fixpoint.
 
 Handles the shape contract between the algorithm (n vars × d values, any sizes)
 and the kernels (padded, flattened, optionally bitpacked). The padding contract
-itself lives in `repro.core.engine` (DESIGN.md §2) — this module only reshapes
-and bitpacks the padded tensors into the kernels' layouts:
+itself lives in `repro.core.engine` (DESIGN.md §2) — this module only lays the
+padded tensors out the way `rtac_support` reads them:
 
-- revise_fn factories are ``lru_cache``-d on (shapes, blocks) so the returned
-  function object is stable and keys `enforce_generic`'s jit cache correctly.
+- one set of closure factories, keyed on the encoding (``"dense"`` int8 or
+  ``"packed"`` int32 words) and the interpret mode. They are ``lru_cache``-d,
+  so each returned function object is stable and keys the jit caches; kernel
+  dims are read from the traced shapes, so one closure serves every bucket.
 - network preparation (padding + transpose + bitpack of the O(n²d²) constraint
   tensor) is memoized per CSP identity, so repeated preparation of the same
   network is free. The Engine layer (`repro.engines.pallas`) calls
-  ``prepare_dense``/``prepare_packed`` once per CSP by construction — the
-  deprecated one-shot ``enforce_*_kernel`` entry points are gone; go through
-  ``repro.engines.get_engine("pallas_dense" | "pallas_packed")``.
+  ``prepare_network`` once per CSP by construction.
 
-On this CPU container the kernels run in ``interpret=True`` (Pallas executes
-the kernel body in Python); on a real TPU pass ``interpret=False``.
+Whether Pallas interprets or compiles is decided in one place,
+`interpret_mode`, from the backend: the CPU interprets the kernel bodies, a
+TPU compiles them. Engines read it when they are constructed and pass it to
+every kernel call.
 """
 
 from __future__ import annotations
 
 import functools
 import weakref
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,23 +32,35 @@ from repro import faults, obs
 from repro.core import rtac
 from repro.core.csp import CSP
 from repro.core.engine import pad_dom, pad_network, padded_shape
-from . import autotune, bitpack_support, ref, rtac_support
+from . import autotune, ref, rtac_support
 
 Array = jax.Array
+
+#: value-axis multiple both encodings pad d to (the one place it is set —
+#: engines sizing slot tables without a CSP import this)
+D_MULT = 8
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted on this process's backend: the
+    CPU interprets, a TPU compiles, any other backend is refused."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas engines run on 'cpu' (interpreted) or 'tpu'; got {backend!r}")
 
 
 def _count_build(name: str) -> None:
     """Registry tick for one kernel-closure construction. The factories are
-    ``lru_cache``-d, so this fires once per distinct (shape, blocks, mode)
-    program family — the compiled-program census the obs CLI reports."""
+    ``lru_cache``-d, so this fires once per distinct (encoding, mode) program
+    family — the compiled-program census the obs CLI reports."""
     obs.counter_add("kernels.fn_builds")
     obs.counter_add(f"kernels.fn_builds.{name}")
 
-#: value-axis tile multiple both kernels pad d to (the one place it is set —
-#: engines sizing slot tables without a CSP import this)
-D_MULT = 8
 
-# (kind, blocks, id(cons), id(mask)) -> (wref(cons), wref(mask), (network, dims)).
+# (kind, n_block, id(cons), id(mask)) -> (wref(cons), wref(mask), (network, dims)).
 # Keyed by the identity of BOTH network tensors — the prepared form embeds the
 # mask, so a CSP sharing `cons` but carrying a different `mask` must miss. The
 # weakrefs guard against id() reuse after gc, and their callbacks evict the
@@ -55,8 +68,8 @@ D_MULT = 8
 _NETWORK_CACHE: dict = {}
 
 
-def _cached(kind: str, csp: CSP, block_rx: int, block_ry: int, build):
-    key = (kind, block_rx, block_ry, id(csp.cons), id(csp.mask))
+def _cached(kind: str, csp: CSP, n_block: int, build):
+    key = (kind, n_block, id(csp.cons), id(csp.mask))
     hit = _NETWORK_CACHE.get(key)
     if hit is not None and hit[0]() is csp.cons and hit[1]() is csp.mask:
         return hit[2]
@@ -71,291 +84,115 @@ def _cached(kind: str, csp: CSP, block_rx: int, block_ry: int, build):
     return value
 
 
-# ---------------------------------------------------------------------------
-# Dense uint8 kernel
-# ---------------------------------------------------------------------------
+def kernel_dims(kind: str, n: int, d: int, n_block: int = 8):
+    """Kernel coordinates for caller shape (n, d): (n_p, d_p) dense,
+    (n_p, d_p, W) packed."""
+    n_p, d_p = padded_shape(n, d, n_block, D_MULT)
+    if kind == "dense":
+        return n_p, d_p
+    return n_p, d_p, rtac_support.words_per_domain(d_p)
 
 
-@functools.lru_cache(maxsize=None)
-def _dense_revise_fn(n_p: int, d_p: int, block_rx: int, block_ry: int, interpret: bool):
-    _count_build("dense_revise")
-    def revise_fn(net, dom, changed):
-        cons2, mask_u8 = net
-        viol = rtac_support.dense_revise(
-            cons2,
-            dom.astype(jnp.uint8).reshape(1, n_p * d_p),
-            changed.astype(jnp.uint8).reshape(1, n_p),
-            mask_u8,
-            d=d_p,
-            block_rx=block_rx,
-            block_ry=block_ry,
-            interpret=interpret,
-        )
-        return viol.reshape(n_p, d_p).astype(jnp.bool_)
-
-    return revise_fn
+def encode_cons(kind: str, cons: Array) -> Array:
+    """Padded (n, n, d, d) bool constraints -> the kernel's network tensor:
+    dense (N, N) int8 with rows (y, b) and columns (x, a); packed (W, n, N)
+    int32 with bit b of word w = C[x, y, a, 32w + b] at [w, y, x·d + a]."""
+    n, _, d, _ = cons.shape
+    if kind == "dense":
+        return jnp.transpose(cons, (1, 3, 0, 2)).reshape(n * d, n * d).astype(jnp.int8)
+    words = ref.pack_bits_ref(cons)  # (x, y, a, W) uint32
+    w = words.shape[-1]
+    words = jnp.transpose(words, (3, 1, 0, 2)).reshape(w, n, n * d)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
-def prepare_dense(csp: CSP, block_rx: int = 8, block_ry: int = 8):
-    """-> (network, dom_padded, (n_p, d_p)). network = (cons2 u8, mask u8).
+def prepare_network(kind: str, csp: CSP, n_block: int = 8):
+    """-> (network, dom_padded, dims). network = (cons, mask u8 (n_p, n_p)).
 
     The network half is memoized per CSP; the domain is padded fresh (O(n·d))."""
-    faults.inject("kernel.launch", kernel="dense")
+    faults.inject("kernel.launch", kernel=kind)
 
     def build():
-        cons, mask, n_p, d_p = pad_network(csp, max(block_rx, block_ry), D_MULT)
-        cons2 = (
-            jnp.transpose(cons, (0, 2, 1, 3))
-            .reshape(n_p * d_p, n_p * d_p)
-            .astype(jnp.uint8)
-        )
-        return (cons2, mask.astype(jnp.uint8)), (n_p, d_p)
+        cons, mask, n_p, d_p = pad_network(csp, n_block, D_MULT)
+        network = (encode_cons(kind, cons), mask.astype(jnp.uint8))
+        return network, kernel_dims(kind, n_p, d_p, n_block)
 
-    network, (n_p, d_p) = _cached("dense", csp, block_rx, block_ry, build)
-    return network, pad_dom(csp.dom, n_p, d_p), (n_p, d_p)
+    network, dims = _cached(kind, csp, n_block, build)
+    return network, pad_dom(csp.dom, dims[0], dims[1]), dims
+
+
+prepare_dense = functools.partial(prepare_network, "dense")
+prepare_packed = functools.partial(prepare_network, "packed")
+
+
+def kernel_operands(net_g, doms, changed):
+    """Rows in padded (R, n, d) coordinates -> the kernel operands."""
+    cons_g, mask_g = net_g
+    r, n_p, d_p = doms.shape
+    dom = doms.astype(jnp.int32).reshape(r, 1, n_p * d_p)
+    seed = changed.astype(jnp.int32).reshape(r, n_p, 1)
+    # mask[r, y, x·d + a] = constrained(x, y): the mask spread over x's values
+    mask = jnp.repeat(jnp.swapaxes(mask_g, 1, 2), d_p, axis=2).astype(jnp.int8)
+    return cons_g, dom, seed, mask
+
+
+# ---------------------------------------------------------------------------
+# Closure factories
+# ---------------------------------------------------------------------------
 
 
 @functools.lru_cache(maxsize=None)
-def _dense_rows_fn(n_p: int, d_p: int, block_rx: int, block_ry: int, interpret: bool):
-    """Stacked revise-rows closure (rtac.ReviseRowsFn) for the dense u8 kernel:
-    ``net_g`` leaves carry a leading row axis (gathered from the slot table)."""
-    _count_build("dense_rows")
+def rows_fn(kind: str, interpret: bool):
+    """Stacked revise-rows closure (`rtac.ReviseRowsFn`): ``net_g`` leaves
+    carry a leading row axis (gathered from the slot table); returns the
+    violated (R, n_p, d_p) bool of one sweep per row."""
+    _count_build(f"{kind}_rows")
 
     def revise_rows(net_g, doms, changed):
-        cons_g, mask_g = net_g  # (R, n_p*d_p, n_p*d_p) u8, (R, n_p, n_p) u8
-        r = doms.shape[0]
-        viol = rtac_support.dense_revise_stacked(
-            cons_g,
-            doms.astype(jnp.uint8).reshape(r, 1, n_p * d_p),
-            changed.astype(jnp.uint8).reshape(r, 1, n_p),
-            mask_g,
-            d=d_p,
-            block_rx=block_rx,
-            block_ry=block_ry,
-            interpret=interpret,
+        r, n_p, d_p = doms.shape
+        block_r = autotune.effective_block_r(rtac_support.max_block_r(kind, n_p, d_p), r)
+        viol = rtac_support.revise_rows(
+            *kernel_operands(net_g, doms, changed),
+            encoding=kind, d=d_p, block_r=block_r, interpret=interpret,
         )
         return viol.reshape(r, n_p, d_p).astype(jnp.bool_)
 
     return revise_rows
 
 
-# ---------------------------------------------------------------------------
-# Bitpacked uint32 kernel
-# ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def revise_fn(kind: str, interpret: bool):
+    """Single-network revise closure (`rtac.ReviseFn`): the stacked kernel
+    with one row."""
+    _count_build(f"{kind}_revise")
+    rows = rows_fn(kind, interpret)
 
+    def revise(net, dom, changed):
+        net_g = jax.tree_util.tree_map(lambda t: t[None], net)
+        return rows(net_g, dom[None], changed[None])[0]
 
-def pack_network(cons: Array, n_p: int, d_p: int) -> Tuple[Array, int]:
-    """(n_p,n_p,d_p,d_p) bool -> ((n_p*d_p, n_p*W) uint32, W)."""
-    packed = ref.pack_bits_ref(cons)  # (n_p, n_p, d_p, W)
-    w = packed.shape[-1]
-    return jnp.transpose(packed, (0, 2, 1, 3)).reshape(n_p * d_p, n_p * w), w
+    return revise
 
 
 @functools.lru_cache(maxsize=None)
-def _packed_revise_fn(
-    n_p: int, d_p: int, w: int, block_rx: int, block_ry: int, interpret: bool
-):
-    _count_build("packed_revise")
-    def revise_fn(net, dom, changed):
-        cons_p2, mask_u8 = net
-        dom_pk = ref.pack_bits_ref(dom).reshape(1, n_p * w)
-        viol = bitpack_support.packed_revise(
-            cons_p2,
-            dom_pk,
-            changed.astype(jnp.uint8).reshape(1, n_p),
-            mask_u8,
-            d=d_p,
-            w=w,
-            block_rx=block_rx,
-            block_ry=block_ry,
-            interpret=interpret,
-        )
-        return viol.reshape(n_p, d_p).astype(jnp.bool_)
-
-    return revise_fn
-
-
-def prepare_packed(csp: CSP, block_rx: int = 8, block_ry: int = 8):
-    """-> (network, dom_padded, (n_p, d_p, w)); network memoized per CSP."""
-    faults.inject("kernel.launch", kernel="packed")
-
-    def build():
-        cons, mask, n_p, d_p = pad_network(csp, max(block_rx, block_ry), D_MULT)
-        cons_p2, w = pack_network(cons, n_p, d_p)
-        return (cons_p2, mask.astype(jnp.uint8)), (n_p, d_p, w)
-
-    network, (n_p, d_p, w) = _cached("packed", csp, block_rx, block_ry, build)
-    return network, pad_dom(csp.dom, n_p, d_p), (n_p, d_p, w)
-
-
-# ---------------------------------------------------------------------------
-# Fused assign + revise frontier entries (DESIGN.md §8)
-# ---------------------------------------------------------------------------
-
-
-def _padded_seed(var, n: int, n_p: int):
-    """The Prop. 2 revision seed in padded coordinates: ``one_hot(var)`` for
-    assigned rows, all real variables for root rows (``var < 0``); padded
-    variables are never seeded (their domains never shrink). Identical to
-    `pad_changed` applied to the caller-coordinate seed."""
-    ar = jnp.arange(n_p, dtype=var.dtype)[None, :]
-    is_root = (var < 0)[:, None]
-    return jnp.where(is_root, ar < n, ar == jnp.maximum(var, 0)[:, None])
-
-
-@functools.lru_cache(maxsize=None)
-def _dense_frontier_fn(block_rx: int, block_ry: int, interpret: bool):
-    """Fused assign+revise frontier dispatch for the dense u8 kernel: one
-    traced program pads R parent closures into kernel coordinates, applies the
-    batched Alg. 2 assignment (`rtac_support.assign_padded_rows`), and runs
-    the stacked-kernel fixpoint — the device never sees a host-built domain."""
-    _count_build("dense_frontier")
-
-    def assign_enforce_rows(net_g, doms, var, val, idx):
-        r, n, d = doms.shape
-        n_p, d_p = padded_shape(n, d, max(block_rx, block_ry), D_MULT)
-        rows_fn = _dense_rows_fn(n_p, d_p, block_rx, block_ry, interpret)
-        dom_p = rtac_support.assign_padded_rows(pad_dom(doms, n_p, d_p), var, val)
-        ch_p = _padded_seed(var, n, n_p)
-        res = rtac.enforce_rows_generic(net_g, dom_p, ch_p, idx, revise_rows_fn=rows_fn)
-        return rtac.EnforceResult(res.dom[:, :n, :d], res.consistent, res.n_recurrences)
-
-    return assign_enforce_rows
-
-
-@functools.lru_cache(maxsize=None)
-def _packed_frontier_fn(block_rx: int, block_ry: int, interpret: bool):
-    """Fused assign+revise frontier dispatch for the bitpacked u32 kernel
-    (same shape as `_dense_frontier_fn`; the fixpoint packs row domains fresh
-    each recurrence, the networks ride gathered from the packed slot table)."""
-    _count_build("packed_frontier")
-
-    def assign_enforce_rows(net_g, doms, var, val, idx):
-        r, n, d = doms.shape
-        n_p, d_p = padded_shape(n, d, max(block_rx, block_ry), D_MULT)
-        w = -(-d_p // 32)
-        rows_fn = _packed_rows_fn(n_p, d_p, w, block_rx, block_ry, interpret)
-        dom_p = rtac_support.assign_padded_rows(pad_dom(doms, n_p, d_p), var, val)
-        ch_p = _padded_seed(var, n, n_p)
-        res = rtac.enforce_rows_generic(net_g, dom_p, ch_p, idx, revise_rows_fn=rows_fn)
-        return rtac.EnforceResult(res.dom[:, :n, :d], res.consistent, res.n_recurrences)
-
-    return assign_enforce_rows
-
-
-@functools.lru_cache(maxsize=None)
-def _packed_rows_fn(
-    n_p: int, d_p: int, w: int, block_rx: int, block_ry: int, interpret: bool
-):
-    """Stacked revise-rows closure (rtac.ReviseRowsFn) for the bitpacked u32
-    kernel: row domains are packed fresh (O(R·n·d)); the packed networks ride
-    gathered from the (C, n·d, n·W) slot table."""
-    _count_build("packed_rows")
-
-    def revise_rows(net_g, doms, changed):
-        cons_g, mask_g = net_g  # (R, n_p*d_p, n_p*w) u32, (R, n_p, n_p) u8
-        r = doms.shape[0]
-        dom_pk = ref.pack_bits_ref(doms).reshape(r, 1, n_p * w)
-        viol = bitpack_support.packed_revise_stacked(
-            cons_g,
-            dom_pk,
-            changed.astype(jnp.uint8).reshape(r, 1, n_p),
-            mask_g,
-            d=d_p,
-            w=w,
-            block_rx=block_rx,
-            block_ry=block_ry,
-            interpret=interpret,
-        )
-        return viol.reshape(r, n_p, d_p).astype(jnp.bool_)
-
-    return revise_rows
-
-
-# ---------------------------------------------------------------------------
-# Fused in-kernel fixpoint (one launch per round; DESIGN.md §4)
-# ---------------------------------------------------------------------------
-
-
-def _fixpoint_schedule(
-    kind: str, n_p: int, d_p: int, w: int, r: int, block_rx: int, block_ry: int
-):
-    """Resolve the fused-kernel schedule at trace time. R is static inside a
-    traced program, so this is a plain in-memory lookup (`autotune.get_config`
-    never times anything); untuned buckets run the engine defaults. The jitted
-    program bakes the schedule it sees — tune before first dispatch."""
-    cfg = autotune.get_config(kind, n_p, d_p, w, r, block_rx, block_ry)
-    return autotune.TuneConfig(
-        autotune.effective_block_r(cfg.block_r, r),
-        cfg.block_rx, cfg.block_ry, cfg.sweep,
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def _dense_fixpoint_rows_fn(
-    n_p: int, d_p: int, block_rx: int, block_ry: int, interpret: bool
-):
-    """Stacked one-launch fixpoint for the dense u8 kernel. Same signature as
-    `rtac.enforce_rows_generic` (net_g, dom_p, ch_p -> EnforceResult in padded
-    coordinates) so engines can swap it for the stepped path wholesale."""
-    _count_build("dense_fixpoint_rows")
+def fixpoint_rows_fn(kind: str, interpret: bool):
+    """Stacked one-launch fixpoint. Same signature as
+    `rtac.enforce_rows_generic` minus the gather (net_g, dom_p, ch_p ->
+    EnforceResult in padded coordinates), so engines swap it for the stepped
+    path wholesale."""
+    _count_build(f"{kind}_fixpoint_rows")
 
     def fixpoint_rows(net_g, doms, changed):
-        cons_g, mask_g = net_g
-        r = doms.shape[0]
-        cfg = _fixpoint_schedule("dense", n_p, d_p, 0, r, block_rx, block_ry)
-        dom_f, cons_f, k_f = rtac_support.dense_fixpoint_stacked(
-            cons_g,
-            doms.astype(jnp.uint8).reshape(r, 1, n_p * d_p),
-            changed.astype(jnp.uint8).reshape(r, 1, n_p),
-            mask_g,
-            d=d_p,
-            block_r=cfg.block_r,
-            block_rx=cfg.block_rx,
-            block_ry=cfg.block_ry,
-            sweep=cfg.sweep,
-            interpret=interpret,
+        r, n_p, d_p = doms.shape
+        block_r = autotune.fused_block_r(kind, n_p, d_p, r)
+        dom, ok, k = rtac_support.fixpoint_rows(
+            *kernel_operands(net_g, doms, changed),
+            encoding=kind, d=d_p, block_r=block_r, interpret=interpret,
         )
         return rtac.EnforceResult(
-            dom_f.reshape(r, n_p, d_p).astype(jnp.bool_),
-            cons_f[:, 0].astype(jnp.bool_),
-            k_f[:, 0],
-        )
-
-    return fixpoint_rows
-
-
-@functools.lru_cache(maxsize=None)
-def _packed_fixpoint_rows_fn(
-    n_p: int, d_p: int, w: int, block_rx: int, block_ry: int, interpret: bool
-):
-    """Stacked one-launch fixpoint for the bitpacked u32 kernel: row domains
-    are packed ONCE on entry and stay (n, W) u32 words in VMEM across every
-    in-kernel recurrence (the stepped path re-packs each iteration)."""
-    _count_build("packed_fixpoint_rows")
-
-    def fixpoint_rows(net_g, doms, changed):
-        cons_g, mask_g = net_g
-        r = doms.shape[0]
-        cfg = _fixpoint_schedule("packed", n_p, d_p, w, r, block_rx, block_ry)
-        dom_pk = ref.pack_bits_ref(doms).reshape(r, 1, n_p * w)
-        dom_f, cons_f, k_f = bitpack_support.packed_fixpoint_stacked(
-            cons_g,
-            dom_pk,
-            changed.astype(jnp.uint8).reshape(r, 1, n_p),
-            mask_g,
-            d=d_p,
-            w=w,
-            block_r=cfg.block_r,
-            block_rx=cfg.block_rx,
-            block_ry=cfg.block_ry,
-            sweep=cfg.sweep,
-            interpret=interpret,
-        )
-        return rtac.EnforceResult(
-            dom_f.reshape(r, n_p, d_p).astype(jnp.bool_),
-            cons_f[:, 0].astype(jnp.bool_),
-            k_f[:, 0],
+            dom.reshape(r, n_p, d_p).astype(jnp.bool_),
+            ok[:, 0, 0].astype(jnp.bool_),
+            k[:, 0, 0],
         )
 
     return fixpoint_rows
@@ -371,42 +208,42 @@ def enforce_rows_fused(networks, dom, changed0, instance_idx, fixpoint_rows_fn):
     return fixpoint_rows_fn(net_g, dom, changed0)
 
 
-@functools.lru_cache(maxsize=None)
-def _dense_frontier_fused_fn(block_rx: int, block_ry: int, interpret: bool):
-    """One-launch-per-round frontier dispatch for the dense u8 kernel: pad,
-    batched Alg. 2 assignment, seed — then a single fused fixpoint launch in
-    place of `_dense_frontier_fn`'s stepped while_loop."""
-    _count_build("dense_frontier_fused")
+# ---------------------------------------------------------------------------
+# Fused assign + enforce frontier entries (DESIGN.md §8)
+# ---------------------------------------------------------------------------
 
-    def assign_enforce_rows(net_g, doms, var, val, idx):
-        r, n, d = doms.shape
-        n_p, d_p = padded_shape(n, d, max(block_rx, block_ry), D_MULT)
-        rows_fn = _dense_fixpoint_rows_fn(n_p, d_p, block_rx, block_ry, interpret)
-        dom_p = rtac_support.assign_padded_rows(pad_dom(doms, n_p, d_p), var, val)
-        ch_p = _padded_seed(var, n, n_p)
-        net_rows = jax.tree_util.tree_map(lambda t: t[idx], net_g)
-        res = rows_fn(net_rows, dom_p, ch_p)
-        return rtac.EnforceResult(res.dom[:, :n, :d], res.consistent, res.n_recurrences)
 
-    return assign_enforce_rows
+def _padded_seed(var, n: int, n_p: int):
+    """The Prop. 2 revision seed in padded coordinates: ``one_hot(var)`` for
+    assigned rows, all real variables for root rows (``var < 0``); padded
+    variables are never seeded (their domains never shrink). Identical to
+    `pad_changed` applied to the caller-coordinate seed."""
+    ar = jnp.arange(n_p, dtype=var.dtype)[None, :]
+    is_root = (var < 0)[:, None]
+    return jnp.where(is_root, ar < n, ar == jnp.maximum(var, 0)[:, None])
 
 
 @functools.lru_cache(maxsize=None)
-def _packed_frontier_fused_fn(block_rx: int, block_ry: int, interpret: bool):
-    """One-launch-per-round frontier dispatch for the bitpacked u32 kernel
-    (shape-identical to `_packed_frontier_fn`; domains pack once on entry and
-    the recurrence runs on u32 word planes pinned in VMEM)."""
-    _count_build("packed_frontier_fused")
+def frontier_fn(kind: str, fused: bool, interpret: bool):
+    """Fused assign+enforce frontier dispatch: one traced program pads R
+    parent closures into kernel coordinates, applies the batched Alg. 2
+    assignment (`rtac_support.assign_padded_rows`), and runs the fixpoint —
+    one fused launch, or the stepped while_loop around per-sweep launches.
+    The device never sees a host-built domain."""
+    _count_build(f"{kind}_frontier{'_fused' if fused else ''}")
 
     def assign_enforce_rows(net_g, doms, var, val, idx):
-        r, n, d = doms.shape
-        n_p, d_p = padded_shape(n, d, max(block_rx, block_ry), D_MULT)
-        w = -(-d_p // 32)
-        rows_fn = _packed_fixpoint_rows_fn(n_p, d_p, w, block_rx, block_ry, interpret)
+        _, n, d = doms.shape
+        n_p, d_p = kernel_dims(kind, n, d)[:2]
         dom_p = rtac_support.assign_padded_rows(pad_dom(doms, n_p, d_p), var, val)
         ch_p = _padded_seed(var, n, n_p)
-        net_rows = jax.tree_util.tree_map(lambda t: t[idx], net_g)
-        res = rows_fn(net_rows, dom_p, ch_p)
+        if fused:
+            net_rows = jax.tree_util.tree_map(lambda t: t[idx], net_g)
+            res = fixpoint_rows_fn(kind, interpret)(net_rows, dom_p, ch_p)
+        else:
+            res = rtac.enforce_rows_generic(
+                net_g, dom_p, ch_p, idx, revise_rows_fn=rows_fn(kind, interpret)
+            )
         return rtac.EnforceResult(res.dom[:, :n, :d], res.consistent, res.n_recurrences)
 
     return assign_enforce_rows

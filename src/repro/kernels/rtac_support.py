@@ -1,29 +1,35 @@
-"""Dense RTAC revise kernel — fused support-count + clamp + changed-masked AND-reduce.
+"""RTAC revise and fixpoint kernels — one body, two constraint encodings.
 
-TPU adaptation of the paper's Alg. 1 lines 14-16 (see DESIGN.md §2). The GPU
-implementation is a cuBLAS matmul producing the (n, n, d) support-count tensor in
-HBM, followed by separate clamp/sum/where kernels. The contraction has arithmetic
-intensity ~2 FLOP per constraint byte — memory-bound — so on TPU the correct
-shape is a single streaming pass over the constraint tensor on the VPU with
-everything fused, never materializing the (n, n, d) intermediate.
+TPU adaptation of the paper's Alg. 1 lines 14-16 (DESIGN.md §4). Every array
+a kernel touches is 2-D per row with the flattened ``(x, a)`` value index on
+the lanes, so no block or in-kernel value ever splits or re-tiles the lane
+axis (Mosaic refuses such reshapes), and every BlockSpec is full in its last
+two dimensions (legal under the (8, 128) rule at any shape):
 
-Layout: the 4-D constraint tensor is viewed as a 2-D matrix
-``cons2[(x·d + a), (y·d + b)]`` so VMEM tiles are plain 2-D blocks:
+  dom    (R, 1, N)    int32  row r's domain, N = n·d, lane j = x·d + a
+  seed   (R, n, 1)    int32  the Prop. 2 revision seed, one sublane per y
+  mask   (R, n, N)    int8   mask[r, y, x·d + a] = constrained(x, y)
+  cons   dense:  (R, N, N)     int8   cons[(y, b), (x, a)] = C[x, y, a, b]
+         packed: (R, W, n, N)  int32  bit b of word w = C[x, y, a, 32w + b]
 
-  grid (i over x-row-blocks, j over y-col-blocks)   — j is the reduction dim
-  cons2 block   (BR, BC) uint8   BR = RX·d rows, BC = RY·d cols
-  dom block     (1, BC)   uint8  (flattened domains of the RY vars)
-  changed block (1, RY)   uint8
-  mask block    (RX, RY)  uint8
-  out block     (1, BR)   uint8  — violated, indexed by i only: revisited across
-                                   j with OR-accumulation (sequential TPU grid)
+A sweep (Jacobi: it reads only the pre-sweep domain) computes, for every
+constrained neighbour y, whether (x, a) keeps a support in dom(y):
 
-In-kernel: sup = cons2 * dom (VPU int8), per-y counts by (BR, RY, d) reshape-sum,
-has = cnt>0 | ~mask, partial violated = any_y(changed & ~has) OR-ed into out.
+- dense: ``cnt = spread @ cons`` on the MXU in int8, where ``spread[y, (y',
+  b)] = dom[y', b]·[y = y']`` is the domain laid block-diagonally over the
+  sublanes (built from two iotas, never a reshape);
+- packed: the domain word of each y is summed over its lanes (the bits are
+  disjoint, so the sum is the OR), and ``has = any_w(cons_w & word_w) != 0``
+  on the VPU — 32 values per word, 8x fewer constraint bytes than int8.
 
-Block sizes are multiples of (8, 128) sublane×lane tiles when d permits; ops.py
-pads n and d so every grid cell is full (padding is inert: padded vars are
-unconstrained, never in a domain, never changed).
+``violated[(x, a)] = any_y(seed[y] & mask[y, (x, a)] & ~has[y, (x, a)])`` is
+a sublane reduction, so it lands on the lanes, in the layout of ``dom``.
+
+Two launches share that sweep: `revise_rows` (one sweep, the stepped path's
+per-iteration kernel) and `fixpoint_rows` (the whole recurrence in one launch:
+a `while_loop` inside the kernel, domains resident in VMEM). The grid runs
+over instance blocks of ``block_r`` rows; `max_block_r` sizes it from an
+explicit VMEM budget.
 """
 
 from __future__ import annotations
@@ -37,78 +43,217 @@ from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
 
+ENCODINGS = ("dense", "packed")
 
-def _revise_kernel(cons_ref, dom_ref, changed_ref, mask_ref, out_ref, *, d: int):
-    j = pl.program_id(1)
-
-    br = cons_ref.shape[0]
-    bc = cons_ref.shape[1]
-    rx = mask_ref.shape[0]
-    ry = mask_ref.shape[1]
-
-    c = cons_ref[...]  # (BR, BC) uint8
-    dval = dom_ref[...]  # (1, BC) uint8
-    sup = (c & dval).astype(jnp.int32)  # 0/1 — AND == product for bits
-    # per-y support counts: (BR, RY, d) -> (BR, RY)
-    cnt = jnp.sum(sup.reshape(br, ry, d), axis=-1)
-    # expand mask rows var->values: (RX, RY) -> (BR, RY) via broadcast+reshape
-    m = mask_ref[...].astype(jnp.bool_)  # (RX, RY)
-    m_rows = jnp.broadcast_to(m[:, None, :], (rx, d, ry)).reshape(br, ry)
-    has = (cnt > 0) | ~m_rows  # (BR, RY)
-    ch = changed_ref[...].astype(jnp.bool_)  # (1, RY)
-    viol = jnp.any(ch & ~has, axis=-1)  # (BR,)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] = out_ref[...] | viol[None, :].astype(jnp.uint8)
+#: VMEM a kernel may plan for and is allowed to use (v5e has 128 MiB per
+#: core; the compiler's default scoped limit is far below that)
+VMEM_BUDGET = 96 * 1024 * 1024
 
 
-@functools.partial(
-    jax.jit, static_argnames=("d", "block_rx", "block_ry", "interpret")
-)
-def dense_revise(
-    cons2: Array,  # (n*d, n*d) uint8 — flattened [x,a],[y,b]
-    dom_flat: Array,  # (1, n*d) uint8
-    changed: Array,  # (1, n) uint8
-    mask: Array,  # (n, n) uint8
-    *,
-    d: int,
-    block_rx: int = 8,  # x-vars per row block
-    block_ry: int = 8,  # y-vars per col block
-    interpret: bool = True,
+def words_per_domain(d: int) -> int:
+    return -(-d // 32)
+
+
+def vmem_bytes(encoding: str, n: int, d: int, block_r: int) -> int:
+    """Planned VMEM of one grid cell: double-buffered operands plus ~8 live
+    (n, N) int32 temporaries of the sweep, per row."""
+    nd = n * d
+    if encoding == "dense":
+        cons = nd * nd
+    else:
+        cons = words_per_domain(d) * n * nd * 4
+    operands = cons + n * nd + 8 * nd + 4 * n + 8
+    return block_r * (2 * operands + 8 * n * nd * 4)
+
+
+def max_block_r(encoding: str, n: int, d: int, budget: int = VMEM_BUDGET) -> int:
+    """Largest power-of-two block_r (≤ 8) whose cell fits ``budget``; 0 if
+    not even one row fits (the network must then stream from HBM)."""
+    for br in (8, 4, 2, 1):
+        if vmem_bytes(encoding, n, d, br) <= budget:
+            return br
+    return 0
+
+
+def _compiler_params(encoding: str, n: int, d: int, block_r: int):
+    need = vmem_bytes(encoding, n, d, block_r)
+    if need > VMEM_BUDGET:
+        raise ValueError(
+            f"{encoding} kernel at (n={n}, d={d}), block_r={block_r} plans "
+            f"{need / 2**20:.1f} MiB of VMEM, over the {VMEM_BUDGET / 2**20:.0f} MiB budget"
+        )
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET)
+
+
+# ---------------------------------------------------------------------------
+# The sweep (shared by both launches)
+# ---------------------------------------------------------------------------
+
+
+def _layout(n: int, d: int):
+    """(blk, a): blk[y, j] — lane j = x·d + a belongs to variable y; a[y, j]
+    = j - y·d, the value index inside y's own lane block."""
+    y = jax.lax.broadcasted_iota(jnp.int32, (n, n * d), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (n, n * d), 1)
+    a = j - y * d
+    return (a >= 0) & (a < d), a
+
+
+def _per_var(blk, v):
+    """(b, 1, N) 0/1 -> (b, n, 1): does variable y hold any set lane."""
+    return jnp.max(jnp.where(blk[None], v, 0), axis=2, keepdims=True)
+
+
+def _sweep(encoding, cons, mask, v, seed, blk, a):
+    """One Jacobi revise of ``b`` rows -> violated (b, 1, N) int32 0/1."""
+    if encoding == "dense":
+        spread = jnp.where(blk[None], v, 0).astype(jnp.int8)  # (b, n, N)
+        cnt = jnp.einsum(
+            "bnk,bkm->bnm", spread, cons, preferred_element_type=jnp.int32
+        )
+        has = cnt > 0
+    else:
+        has = None
+        for w in range(cons.shape[1]):
+            in_word = blk & (a >= 32 * w) & (a < 32 * w + 32)
+            weight = jnp.where(
+                in_word, jnp.left_shift(jnp.int32(1), jnp.clip(a - 32 * w, 0, 31)), 0
+            )
+            word = jnp.sum(v * weight[None], axis=2, keepdims=True, dtype=jnp.int32)
+            hit = (cons[:, w] & word) != 0  # (b, n, N)
+            has = hit if has is None else has | hit
+    dead = (~has) & (mask != 0) & (seed != 0)
+    return jnp.max(dead.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _load(cons_ref, mask_ref, d):
+    blk, a = _layout(mask_ref.shape[1], d)
+    return cons_ref[...], mask_ref[...].astype(jnp.int32), blk, a
+
+
+def _revise_kernel(cons_ref, dom_ref, seed_ref, mask_ref, viol_ref, *, encoding, d):
+    cons, mask, blk, a = _load(cons_ref, mask_ref, d)
+    viol_ref[...] = _sweep(encoding, cons, mask, dom_ref[...], seed_ref[...], blk, a)
+
+
+def _fixpoint_kernel(
+    cons_ref, dom_ref, seed_ref, mask_ref, dom_out_ref, ok_out_ref, k_out_ref,
+    *, encoding, d,
+):
+    """``block_r`` rows to their AC fixpoint in one launch. Per-row semantics
+    equal `rtac.enforce_rows_generic`: a row is active while consistent with a
+    non-empty seed, an inactive row's seed is zeroed (its domain freezes), and
+    ``k`` counts only the sweeps the row was active."""
+    cons, mask, blk, a = _load(cons_ref, mask_ref, d)
+    v0 = dom_ref[...]
+    ok0 = jnp.min(_per_var(blk, v0), axis=1, keepdims=True)  # (b, 1, 1)
+
+    def cond(s):
+        _, ch, ok, _ = s
+        return jnp.max(ok * jnp.max(ch, axis=1, keepdims=True)) > 0
+
+    def body(s):
+        v, ch, ok, k = s
+        active = ok * jnp.max(ch, axis=1, keepdims=True)  # (b, 1, 1)
+        viol = _sweep(encoding, cons, mask, v, ch * active, blk, a)
+        new_v = v * (1 - viol)
+        changed = _per_var(blk, v - new_v)
+        ok2 = ok * jnp.min(_per_var(blk, new_v), axis=1, keepdims=True)
+        return new_v, changed, ok2, k + active
+
+    v, _, ok, k = jax.lax.while_loop(
+        cond, body, (v0, seed_ref[...] * ok0, ok0, jnp.zeros_like(ok0))
+    )
+    dom_out_ref[...] = v
+    ok_out_ref[...] = ok
+    k_out_ref[...] = k
+
+
+# ---------------------------------------------------------------------------
+# Launches
+# ---------------------------------------------------------------------------
+
+
+def _in_specs(encoding, cons_shape, n, nd, block_r):
+    if encoding == "dense":
+        cons_spec = pl.BlockSpec((block_r, nd, nd), lambda g: (g, 0, 0))
+    else:
+        cons_spec = pl.BlockSpec((block_r, cons_shape[1], n, nd), lambda g: (g, 0, 0, 0))
+    return [
+        cons_spec,
+        pl.BlockSpec((block_r, 1, nd), lambda g: (g, 0, 0)),
+        pl.BlockSpec((block_r, n, 1), lambda g: (g, 0, 0)),
+        pl.BlockSpec((block_r, n, nd), lambda g: (g, 0, 0)),
+    ]
+
+
+def _check(encoding, dom, seed, mask, d, block_r):
+    if encoding not in ENCODINGS:
+        raise ValueError(f"unknown encoding {encoding!r}; expected one of {ENCODINGS}")
+    r, _, nd = dom.shape
+    n = seed.shape[1]
+    assert nd == n * d and mask.shape == (r, n, nd), (dom.shape, seed.shape, mask.shape)
+    assert r % block_r == 0, (r, block_r)
+    return r, n, nd
+
+
+@functools.partial(jax.jit, static_argnames=("encoding", "d", "block_r", "interpret"))
+def revise_rows(
+    cons: Array, dom: Array, seed: Array, mask: Array,
+    *, encoding: str, d: int, block_r: int, interpret: bool,
 ) -> Array:
-    """Returns violated (1, n*d) uint8. Shapes must be pre-padded so that
-    ``block_rx | n`` and ``block_ry | n``."""
-    nd = cons2.shape[0]
-    n = nd // d
-    assert n % block_rx == 0 and n % block_ry == 0, (n, block_rx, block_ry)
-    br, bc = block_rx * d, block_ry * d
-    grid = (n // block_rx, n // block_ry)
-
+    """R independent revise sweeps, row r against its own network. Returns
+    violated (R, 1, N) int32 0/1."""
+    r, n, nd = _check(encoding, dom, seed, mask, d, block_r)
     return pl.pallas_call(
-        functools.partial(_revise_kernel, d=d),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((1, bc), lambda i, j: (0, j)),
-            pl.BlockSpec((1, block_ry), lambda i, j: (0, j)),
-            pl.BlockSpec((block_rx, block_ry), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, br), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, nd), jnp.uint8),
+        functools.partial(_revise_kernel, encoding=encoding, d=d),
+        grid=(r // block_r,),
+        in_specs=_in_specs(encoding, cons.shape, n, nd, block_r),
+        out_specs=pl.BlockSpec((block_r, 1, nd), lambda g: (g, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, 1, nd), jnp.int32),
+        compiler_params=_compiler_params(encoding, n, d, block_r),
         interpret=interpret,
-    )(cons2, dom_flat, changed, mask)
+        name=f"rtac_revise_{encoding}",
+    )(cons, dom, seed, mask)
+
+
+@functools.partial(jax.jit, static_argnames=("encoding", "d", "block_r", "interpret"))
+def fixpoint_rows(
+    cons: Array, dom: Array, seed: Array, mask: Array,
+    *, encoding: str, d: int, block_r: int, interpret: bool,
+):
+    """R fixpoints in ONE launch. Returns (dom (R, 1, N) int32, consistent
+    (R, 1, 1) int32, k (R, 1, 1) int32) — per row bit-identical to the
+    stepped `rtac.enforce_rows_generic` path."""
+    r, n, nd = _check(encoding, dom, seed, mask, d, block_r)
+    row_spec = pl.BlockSpec((block_r, 1, 1), lambda g: (g, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_fixpoint_kernel, encoding=encoding, d=d),
+        grid=(r // block_r,),
+        in_specs=_in_specs(encoding, cons.shape, n, nd, block_r),
+        out_specs=[
+            pl.BlockSpec((block_r, 1, nd), lambda g: (g, 0, 0)),
+            row_spec,
+            row_spec,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((r, 1, nd), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((r, 1, 1), jnp.int32),
+        ],
+        compiler_params=_compiler_params(encoding, n, d, block_r),
+        interpret=interpret,
+        name=f"rtac_fixpoint_{encoding}",
+    )(cons, dom, seed, mask)
 
 
 def assign_padded_rows(dom_p: Array, var: Array, val: Array) -> Array:
     """Batched Alg. 2 ``assign`` in kernel (padded) coordinates — the fused
     front half of a frontier dispatch (DESIGN.md §8): row i's ``dom(var[i])``
-    collapses to ``{val[i]}`` before the stacked revise fixpoint runs, all in
-    one traced program, so a search round never materializes assigned domains
-    on the host. ``var[i] < 0`` marks a root row, left untouched. ``var``/
-    ``val`` index *caller* coordinates (< n, < d), so the padded tail — absent
+    collapses to ``{val[i]}`` before the fixpoint runs, all in one traced
+    program, so a search round never materializes assigned domains on the
+    host. ``var[i] < 0`` marks a root row, left untouched. ``var``/``val``
+    index *caller* coordinates (< n, < d), so the padded tail — absent
     values, unconstrained singleton variables — is preserved by construction.
     """
     r, _, d_p = dom_p.shape
@@ -116,243 +261,3 @@ def assign_padded_rows(dom_p: Array, var: Array, val: Array) -> Array:
     onehot = (jnp.arange(d_p, dtype=var.dtype)[None, :] == val[:, None]).astype(dom_p.dtype)
     assigned = dom_p.at[jnp.arange(r), safe_var].set(onehot)
     return jnp.where((var < 0)[:, None, None], dom_p, assigned)
-
-
-def _revise_stacked_kernel(cons_ref, dom_ref, changed_ref, mask_ref, out_ref, *, d: int):
-    """Same body as `_revise_kernel`, with a leading instance axis: grid
-    (r, i, j), every block a (1, ...) slice of row r's operands."""
-    j = pl.program_id(2)
-
-    br = cons_ref.shape[1]
-    rx = mask_ref.shape[1]
-    ry = mask_ref.shape[2]
-
-    c = cons_ref[0]  # (BR, BC) uint8
-    dval = dom_ref[0]  # (1, BC) uint8
-    sup = (c & dval).astype(jnp.int32)
-    cnt = jnp.sum(sup.reshape(br, ry, d), axis=-1)
-    m = mask_ref[0].astype(jnp.bool_)  # (RX, RY)
-    m_rows = jnp.broadcast_to(m[:, None, :], (rx, d, ry)).reshape(br, ry)
-    has = (cnt > 0) | ~m_rows
-    ch = changed_ref[0].astype(jnp.bool_)  # (1, RY)
-    viol = jnp.any(ch & ~has, axis=-1)  # (BR,)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] = out_ref[...] | viol[None, None, :].astype(jnp.uint8)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("d", "block_rx", "block_ry", "interpret")
-)
-def dense_revise_stacked(
-    cons_g: Array,  # (R, n*d, n*d) uint8 — row r's network, slot-table gathered
-    dom_flat: Array,  # (R, 1, n*d) uint8
-    changed: Array,  # (R, 1, n) uint8
-    mask: Array,  # (R, n, n) uint8
-    *,
-    d: int,
-    block_rx: int = 8,
-    block_ry: int = 8,
-    interpret: bool = True,
-) -> Array:
-    """R simultaneous dense revisions, each against its own network: the grid
-    carries the instance axis (r, i, j); j is the sequential reduction.
-    Returns violated (R, 1, n*d) uint8."""
-    r, nd = cons_g.shape[0], cons_g.shape[1]
-    n = nd // d
-    assert n % block_rx == 0 and n % block_ry == 0, (n, block_rx, block_ry)
-    br, bc = block_rx * d, block_ry * d
-    grid = (r, n // block_rx, n // block_ry)
-
-    return pl.pallas_call(
-        functools.partial(_revise_stacked_kernel, d=d),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, br, bc), lambda r, i, j: (r, i, j)),
-            pl.BlockSpec((1, 1, bc), lambda r, i, j: (r, 0, j)),
-            pl.BlockSpec((1, 1, block_ry), lambda r, i, j: (r, 0, j)),
-            pl.BlockSpec((1, block_rx, block_ry), lambda r, i, j: (r, i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, br), lambda r, i, j: (r, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((r, 1, nd), jnp.uint8),
-        interpret=interpret,
-    )(cons_g, dom_flat, changed, mask)
-
-
-# ---------------------------------------------------------------------------
-# Fused in-kernel fixpoint (DESIGN.md §4): the WHOLE AC recurrence runs inside
-# one pallas_call — the (n, d) domain planes stay pinned in VMEM across
-# iterations instead of round-tripping HBM once per recurrence.
-# ---------------------------------------------------------------------------
-
-
-def _fixpoint_stacked_kernel(
-    cons_ref, dom_ref, changed_ref, mask_ref,
-    dom_out_ref, cons_out_ref, k_out_ref, flags_ref,
-    *, d: int, block_rx: int, block_ry: int, sweep: str,
-):
-    """One grid cell = ``block_r`` instances run to their AC fixpoint.
-
-    The recurrence is a `jax.lax.while_loop` INSIDE the kernel body carrying
-    (dom, changed, consistent, k); per-row semantics are bit-identical to
-    `rtac.enforce_rows_generic` (active masking freezes converged/wiped-out
-    rows, ``k`` counts only active steps). Each revise sweep walks the
-    constraint block in (block_rx·d × block_ry·d) tiles; ``sweep`` picks the
-    loop-nest order ("xy" = x-outer, "yx" = y-outer). Both orders OR into the
-    same violated accumulator against the PRE-sweep domain (Jacobi), so the
-    schedule knob never changes results — only VMEM access order.
-
-    ``flags_ref`` is SMEM scalar memory: [0] the convergence flag (1 while any
-    row in the cell is still active), [1] the sweep counter. The per-row
-    verdicts and recurrence counts are emitted as kernel outputs.
-    """
-    b = cons_ref.shape[0]
-    nd = cons_ref.shape[1]
-    n = nd // d
-    nx = n // block_rx
-    ny = n // block_ry
-    brd = block_rx * d
-    bcd = block_ry * d
-
-    m = mask_ref[...].astype(jnp.bool_)  # (B, n, n)
-
-    dom0 = dom_ref[...].reshape(b, nd)  # (B, nd) uint8
-    ch0 = changed_ref[...].reshape(b, n).astype(jnp.bool_)
-    consistent0 = ~jnp.any(
-        jnp.sum(dom0.reshape(b, n, d).astype(jnp.int32), axis=-1) == 0, axis=-1
-    )  # (B,)
-
-    flags_ref[0] = jnp.int32(1)  # convergence flag: 1 while any row active
-    flags_ref[1] = jnp.int32(0)  # in-kernel sweep counter
-
-    def tile(ix, iy, dom, seed, acc):
-        """OR one (brd × bcd) tile's violations into the x-slab ``acc``."""
-        cs = pl.load(
-            cons_ref, (slice(None), pl.ds(ix * brd, brd), pl.ds(iy * bcd, bcd))
-        )  # (B, brd, bcd)
-        dv = jax.lax.dynamic_slice(dom, (0, iy * bcd), (b, bcd))
-        sup = (cs & dv[:, None, :]).astype(jnp.int32)
-        cnt = jnp.sum(sup.reshape(b, brd, block_ry, d), axis=-1)  # (B, brd, RY)
-        ms = jax.lax.dynamic_slice(
-            m, (0, ix * block_rx, iy * block_ry), (b, block_rx, block_ry)
-        )
-        m_rows = jnp.broadcast_to(
-            ms[:, :, None, :], (b, block_rx, d, block_ry)
-        ).reshape(b, brd, block_ry)
-        has = (cnt > 0) | ~m_rows
-        sd = jax.lax.dynamic_slice(seed, (0, iy * block_ry), (b, block_ry))
-        return acc | jnp.any(sd[:, None, :] & ~has, axis=-1)  # (B, brd)
-
-    def revise(dom, seed):
-        """Full blocked sweep -> violated (B, nd) bool (Jacobi: reads only the
-        pre-sweep ``dom``, so "xy" and "yx" orders are bit-identical)."""
-        viol = jnp.zeros((b, nd), jnp.bool_)
-        if sweep == "xy":
-            def x_body(ix, v):
-                slab = jax.lax.fori_loop(
-                    0, ny, lambda iy, a: tile(ix, iy, dom, seed, a),
-                    jnp.zeros((b, brd), jnp.bool_),
-                )
-                return jax.lax.dynamic_update_slice(v, slab, (0, ix * brd))
-
-            viol = jax.lax.fori_loop(0, nx, x_body, viol)
-        else:  # "yx"
-            def y_body(iy, v):
-                def x_body(ix, vv):
-                    old = jax.lax.dynamic_slice(vv, (0, ix * brd), (b, brd))
-                    return jax.lax.dynamic_update_slice(
-                        vv, tile(ix, iy, dom, seed, old), (0, ix * brd)
-                    )
-
-                return jax.lax.fori_loop(0, nx, x_body, v)
-
-            viol = jax.lax.fori_loop(0, ny, y_body, viol)
-        return viol
-
-    def cond(s):
-        dom, ch, ok, k = s
-        return jnp.any(ok & jnp.any(ch, axis=-1))
-
-    def body(s):
-        dom, ch, ok, k = s
-        active = ok & jnp.any(ch, axis=-1)  # (B,)
-        seed = ch & active[:, None]
-        viol = revise(dom, seed)
-        new_dom = dom & ~viol.astype(jnp.uint8)
-        changed = jnp.any((new_dom != dom).reshape(b, n, d), axis=-1)
-        ok2 = ok & ~jnp.any(
-            jnp.sum(new_dom.reshape(b, n, d).astype(jnp.int32), axis=-1) == 0,
-            axis=-1,
-        )
-        flags_ref[0] = jnp.any(ok2 & jnp.any(changed, axis=-1)).astype(jnp.int32)
-        flags_ref[1] = flags_ref[1] + 1
-        return (new_dom, changed, ok2, k + active.astype(jnp.int32))
-
-    state = (
-        dom0,
-        ch0 & consistent0[:, None],
-        consistent0,
-        jnp.zeros((b,), jnp.int32),
-    )
-    dom_f, _, cons_f, k_f = jax.lax.while_loop(cond, body, state)
-    dom_out_ref[...] = dom_f.reshape(b, 1, nd)
-    cons_out_ref[...] = cons_f[:, None].astype(jnp.uint8)
-    k_out_ref[...] = k_f[:, None]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("d", "block_r", "block_rx", "block_ry", "sweep", "interpret"),
-)
-def dense_fixpoint_stacked(
-    cons_g: Array,  # (R, n*d, n*d) uint8 — row r's network, slot-table gathered
-    dom_flat: Array,  # (R, 1, n*d) uint8 — assignment already applied
-    changed: Array,  # (R, 1, n) uint8 — the Prop. 2 revision seed
-    mask: Array,  # (R, n, n) uint8
-    *,
-    d: int,
-    block_r: int = 8,
-    block_rx: int = 8,
-    block_ry: int = 8,
-    sweep: str = "xy",
-    interpret: bool = True,
-):
-    """R dense fixpoints in ONE launch: grid over instance blocks of
-    ``block_r`` rows, the whole recurrence inside each cell. Returns
-    (dom (R, 1, n·d) u8, consistent (R, 1) u8, k (R, 1) i32) — per-row
-    bit-identical to the stepped `rtac.enforce_rows_generic` path."""
-    r, nd = cons_g.shape[0], cons_g.shape[1]
-    n = nd // d
-    assert r % block_r == 0, (r, block_r)
-    assert n % block_rx == 0 and n % block_ry == 0, (n, block_rx, block_ry)
-    assert sweep in ("xy", "yx"), sweep
-    grid = (r // block_r,)
-
-    return pl.pallas_call(
-        functools.partial(
-            _fixpoint_stacked_kernel,
-            d=d, block_rx=block_rx, block_ry=block_ry, sweep=sweep,
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_r, nd, nd), lambda g: (g, 0, 0)),
-            pl.BlockSpec((block_r, 1, nd), lambda g: (g, 0, 0)),
-            pl.BlockSpec((block_r, 1, n), lambda g: (g, 0, 0)),
-            pl.BlockSpec((block_r, n, n), lambda g: (g, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_r, 1, nd), lambda g: (g, 0, 0)),
-            pl.BlockSpec((block_r, 1), lambda g: (g, 0)),
-            pl.BlockSpec((block_r, 1), lambda g: (g, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, 1, nd), jnp.uint8),
-            jax.ShapeDtypeStruct((r, 1), jnp.uint8),
-            jax.ShapeDtypeStruct((r, 1), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
-        interpret=interpret,
-    )(cons_g, dom_flat, changed, mask)

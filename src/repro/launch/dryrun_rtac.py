@@ -1,44 +1,41 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+"""Ahead-of-time compile of the sharded RTAC enforcer for a four-chip v5e host.
 
-"""Production-scale dry-run for the PAPER'S OWN workload — distributed RTAC.
+Compiles `core/sharded.py`'s shard_map fixpoint for a *described* ``v5e:2x2``
+topology (no chip needed: the TPU compiler runs here and refuses what the
+chip would refuse), with the constraint x-rows sharded over the 4 chips'
+'model' axis and a batch of search-node domains replicated over 'data'.
+Prints per-device memory, cost per recurrence, and the collectives the
+compiler put in, and writes one JSON record per variant under
+``artifacts/dryrun/``.
 
-The "most representative of the paper's technique" hillclimb cell
-(EXPERIMENTS.md §Perf): a production CSP (n=4096 vars, d=32 values — the
-constraint tensor is 16 GiB dense, 64 MiB/chip over the model axis) with a
-batch of 512 search-node domains over (pod ×) data, enforced by the
-shard_map fixpoint of `core/sharded.py`.
-
-Variants (the hillclimb axis):
+Variants (the encoding of the support test):
   einsum-bf16   paper-faithful tensorized contraction (matmul on the MXU)
-  einsum-u8     dense uint8 support test on the VPU (2× less traffic)
+  einsum-u8     dense uint8 support test (2× less traffic)
   bitpacked     uint32 AND/any words (16× less constraint traffic than bf16)
 
 Note on counting: the fixpoint is a `while` loop whose body XLA counts once —
-all numbers below are therefore PER RECURRENCE (multiply by the empirical
-3–5 recurrences of Table 1 for a full enforcement).
+all numbers below are therefore PER RECURRENCE.
 
-    python -m repro.launch.dryrun_rtac [--mesh both]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun_rtac [--n 4096 --d 32]
 """
 
 import argparse
 import json
+import os
 import sys
 import time
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.engines import ShardedEngine
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh
 from repro.parallel.hlo_stats import collective_stats, total_wire_bytes
 
 ART_DIR = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
-
-N_VARS = 4096
-DOM = 32
-BATCH = 512
+TOPOLOGY = "v5e:2x2"
 
 
 def _mem_dict(compiled) -> dict:
@@ -77,39 +74,39 @@ def _cost_dict(compiled) -> dict:
     return {k: float(v) for k, v in ca.items() if isinstance(v, (int, float))}
 
 
-def run_variant(variant: str, mesh_kind: str) -> dict:
-    mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
-    batch_axes = ("pod", "data") if mesh_kind == "multi" else ("data",)
+def compile_variant(variant: str, devices, n_vars: int, dom: int, batch: int):
+    """Lower + compile the sharded enforcer for ``devices`` on shapes only
+    (no constraint tensor is allocated). Returns the compiled executable."""
+    mesh = make_mesh((1, len(devices)), ("data", "model"), devices=devices)
     impl = "bitpacked" if variant == "bitpacked" else "einsum"
     dtype = {"einsum-bf16": jnp.bfloat16, "einsum-u8": jnp.uint8}.get(variant, jnp.bfloat16)
-    # the engine's AOT hook: the same jitted fn its prepare() would bind,
-    # lowered here on ShapeDtypeStructs (no 16 GiB allocation)
-    eng = ShardedEngine(mesh=mesh, batch_axes=batch_axes, dtype=dtype, impl=impl)
-    enf = eng.build_enforcer()
-
-    w = DOM // 32
-    if variant == "bitpacked":
-        cons = jax.ShapeDtypeStruct((N_VARS, N_VARS, DOM, w), jnp.uint32)
+    eng = ShardedEngine(mesh=mesh, dtype=dtype, impl=impl)
+    model = NamedSharding(mesh, P("model"))
+    data = NamedSharding(mesh, P("data"))
+    if impl == "bitpacked":
+        cons = jax.ShapeDtypeStruct((n_vars, n_vars, dom, -(-dom // 32)), jnp.uint32,
+                                    sharding=model)
     else:
-        cons = jax.ShapeDtypeStruct((N_VARS, N_VARS, DOM, DOM), jnp.bool_)
-    mask = jax.ShapeDtypeStruct((N_VARS, N_VARS), jnp.bool_)
-    dom = jax.ShapeDtypeStruct((BATCH, N_VARS, DOM), jnp.bool_)
-    ch = jax.ShapeDtypeStruct((BATCH, N_VARS), jnp.bool_)
+        cons = jax.ShapeDtypeStruct((n_vars, n_vars, dom, dom), jnp.bool_, sharding=model)
+    mask = jax.ShapeDtypeStruct((n_vars, n_vars), jnp.bool_, sharding=model)
+    doms = jax.ShapeDtypeStruct((batch, n_vars, dom), jnp.bool_, sharding=data)
+    ch = jax.ShapeDtypeStruct((batch, n_vars), jnp.bool_, sharding=data)
+    return eng.build_enforcer().lower(cons, mask, doms, ch).compile()
 
+
+def run_variant(variant: str, devices, n_vars: int, dom: int, batch: int) -> dict:
     t0 = time.time()
-    lowered = enf.lower(cons, mask, dom, ch)
-    compiled = lowered.compile()
+    compiled = compile_variant(variant, devices, n_vars, dom, batch)
     t_compile = time.time() - t0
-    hlo = compiled.as_text()
-    coll = collective_stats(hlo)
+    coll = collective_stats(compiled.as_text())
     rec = {
         "workload": "rtac",
         "variant": variant,
-        "mesh": mesh_kind,
-        "n_vars": N_VARS,
-        "dom": DOM,
-        "batch": BATCH,
-        "n_devices": int(mesh.devices.size),
+        "topology": TOPOLOGY,
+        "n_vars": n_vars,
+        "dom": dom,
+        "batch": batch,
+        "n_devices": len(devices),
         "compile_s": round(t_compile, 2),
         "memory_analysis": _mem_dict(compiled),
         "cost_analysis": _cost_dict(compiled),  # per recurrence (while body)
@@ -119,7 +116,7 @@ def run_variant(variant: str, mesh_kind: str) -> dict:
     ca = rec["cost_analysis"]
     mem = rec["memory_analysis"]
     print(
-        f"[dryrun-rtac] {variant:12s} × {mesh_kind}: compile {t_compile:.1f}s "
+        f"[dryrun-rtac] {variant:12s} on {TOPOLOGY}: compile {t_compile:.1f}s "
         f"flops/dev={ca.get('flops', 0):.3e} bytes/dev={ca.get('bytes accessed', 0):.3e} "
         f"wire/dev={rec['collective_wire_bytes']:.3e}B "
         f"temp={mem.get('temp_size_in_bytes', 0)/2**30:.2f}GiB "
@@ -130,19 +127,21 @@ def run_variant(variant: str, mesh_kind: str) -> dict:
 
 
 def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--mesh", default="both", choices=["single", "multi", "both"])
-    ap.add_argument(
-        "--variants", default="einsum-bf16,einsum-u8,bitpacked"
-    )
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--n", type=int, default=4096, help="variables")
+    ap.add_argument("--d", type=int, default=32, help="domain size")
+    ap.add_argument("--batch", type=int, default=64, help="domains per enforcement")
+    ap.add_argument("--variants", default="einsum-bf16,einsum-u8,bitpacked")
     args = ap.parse_args()
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    devices = topologies.get_topology_desc(platform="tpu", topology_name=TOPOLOGY).devices
     ART_DIR.mkdir(parents=True, exist_ok=True)
-    meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]
-    for mesh_kind in meshes:
-        for variant in args.variants.split(","):
-            rec = run_variant(variant, mesh_kind)
-            path = ART_DIR / f"rtac__{variant}__{mesh_kind}.json"
-            path.write_text(json.dumps(rec, indent=1))
+    for variant in args.variants.split(","):
+        rec = run_variant(variant, devices, args.n, args.d, args.batch)
+        path = ART_DIR / f"rtac__{variant}__n{args.n}_d{args.d}.json"
+        path.write_text(json.dumps(rec, indent=1))
     return 0
 
 

@@ -1,38 +1,20 @@
-"""Production mesh construction.
+"""Device meshes for the sharded RTAC path.
 
-``make_production_mesh`` is a FUNCTION (importing this module never touches jax
-device state). Single pod: (data=16, model=16) = 256 chips; multi-pod adds the
-leading 'pod' axis (2 × 256 = 512 chips) carrying only data-parallel gradient
-traffic (TP stays intra-pod — inter-pod links are the slow tier, DESIGN.md §5).
+``make_mesh`` is a FUNCTION (importing this module never touches jax device
+state). Meshes are built from the devices that exist — the process's own, or
+a described topology's for an ahead-of-time compile.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# ``AxisType`` only exists in newer jax releases; feature-detect so this module
-# imports (and plain Meshes work) on the installed version.
-try:  # pragma: no cover - depends on installed jax
-    from jax.sharding import AxisType
-
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: no explicit axis types
-    AxisType = None
-    _AXIS_KW = lambda n: {}
+from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
-
-
-def make_mesh(shape, axes) -> Mesh:
-    """Arbitrary mesh (tests / examples / elasticity)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_AXIS_KW(len(axes)))
-
-
-def host_device_mesh(n_data: int = 1, n_model: int = 1) -> Mesh:
-    """Small mesh over however many (host) devices exist — smoke/integration."""
-    return make_mesh((n_data, n_model), ("data", "model"))
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """A mesh of ``shape`` over ``axes`` (Auto axis types), on ``devices``
+    (default: this process's devices)."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes), **kw
+    )

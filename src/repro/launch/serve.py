@@ -25,6 +25,7 @@ import argparse
 from pathlib import Path
 
 from repro import faults, obs
+from repro.launch import compile_cache
 from repro.service import (
     DEFAULT_VARIANTS,
     FastForwardClock,
@@ -188,6 +189,7 @@ def main(argv=None):
     )
     ap.add_argument("--faults-seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     serve(
         families=[f.strip() for f in args.families.split(",") if f.strip()],
         trace=args.trace,

@@ -288,11 +288,8 @@ class SolverService:
         name = getattr(primary, "name", None)
         from repro.engines import get_engine
 
-        if name and getattr(primary, "fused_fixpoint", False):
-            try:
-                ladder.append(get_engine(name, fixpoint="stepped"))
-            except (KeyError, TypeError, ValueError):
-                pass
+        if getattr(primary, "fused_fixpoint", False) and hasattr(primary, "stepped"):
+            ladder.append(primary.stepped())
         if name != "einsum":
             ladder.append(get_engine("einsum"))
         return ladder

@@ -40,12 +40,10 @@ Spec layout (``schema = "repro-sweep/v1"``)::
     series = "n"
     claim = "..."
 
-TOML support: CI's tier-1 matrix still runs Python 3.10, which has no
-``tomllib``, so this module carries a minimal parser for exactly the subset
-the specs use (``[table]`` / ``[table.sub]`` headers, ``key = value`` with
-strings, ints, floats, booleans, and flat homogeneous arrays, ``#`` comments).
-When ``tomllib`` is importable it is preferred; `dumps_toml` emits the same
-subset, and the spec round-trip is tested through both parsers.
+TOML: specs are parsed with the standard library's ``tomllib``; `dumps_toml`
+emits the subset the specs use (``[table]`` / ``[table.sub]`` headers,
+``key = value`` with strings, ints, floats, booleans, and flat homogeneous
+arrays), and the spec round-trip is tested through both.
 """
 
 from __future__ import annotations
@@ -53,14 +51,11 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import json
+import tomllib
 import zlib
 from pathlib import Path
 from typing import Any, Dict, List, Mapping, Optional, Tuple
 
-try:  # Python >= 3.11
-    import tomllib as _tomllib
-except ImportError:  # Python 3.10: the subset parser below takes over
-    _tomllib = None
 
 #: artifact + spec wire schema; bump together with the cell-record layout
 SCHEMA = "repro-sweep/v1"
@@ -81,100 +76,9 @@ NON_WORKLOAD_KEYS = ("engine", "rate")
 # --------------------------------------------------------------------------
 
 
-def _parse_scalar(tok: str, where: str):
-    tok = tok.strip()
-    if not tok:
-        raise ValueError(f"{where}: empty value")
-    if tok.startswith('"') and tok.endswith('"') and len(tok) >= 2:
-        body = tok[1:-1]
-        if '"' in body or "\\" in body:
-            raise ValueError(f"{where}: escapes/quotes in strings unsupported")
-        return body
-    if tok in ("true", "false"):
-        return tok == "true"
-    try:
-        return int(tok)
-    except ValueError:
-        pass
-    try:
-        return float(tok)
-    except ValueError:
-        raise ValueError(f"{where}: cannot parse value {tok!r}") from None
-
-
-def _split_array(body: str, where: str) -> List[str]:
-    """Split a flat array body on commas, respecting string quotes."""
-    items, depth, cur = [], False, []
-    for ch in body:
-        if ch == '"':
-            depth = not depth
-            cur.append(ch)
-        elif ch == "," and not depth:
-            items.append("".join(cur))
-            cur = []
-        else:
-            cur.append(ch)
-    if depth:
-        raise ValueError(f"{where}: unterminated string in array")
-    tail = "".join(cur).strip()
-    if tail:
-        items.append(tail)
-    return [i for i in (s.strip() for s in items) if i]
-
-
-def _parse_toml_subset(text: str) -> Dict[str, Any]:
-    """Parse the spec TOML subset (see module docstring) into nested dicts."""
-    root: Dict[str, Any] = {}
-    table = root
-    for lineno, raw in enumerate(text.splitlines(), 1):
-        where = f"line {lineno}"
-        line = raw.strip()
-        # strip comments (respecting strings)
-        if "#" in line:
-            out, in_str = [], False
-            for ch in line:
-                if ch == '"':
-                    in_str = not in_str
-                if ch == "#" and not in_str:
-                    break
-                out.append(ch)
-            line = "".join(out).strip()
-        if not line:
-            continue
-        if line.startswith("["):
-            if not line.endswith("]") or line.startswith("[["):
-                raise ValueError(f"{where}: unsupported table header {line!r}")
-            table = root
-            for part in line[1:-1].strip().split("."):
-                if not part:
-                    raise ValueError(f"{where}: bad table name {line!r}")
-                table = table.setdefault(part, {})
-                if not isinstance(table, dict):
-                    raise ValueError(f"{where}: {part!r} is not a table")
-            continue
-        if "=" not in line:
-            raise ValueError(f"{where}: expected key = value, got {line!r}")
-        key, _, val = line.partition("=")
-        key, val = key.strip(), val.strip()
-        if not key:
-            raise ValueError(f"{where}: empty key")
-        if val.startswith("["):
-            if not val.endswith("]"):
-                raise ValueError(f"{where}: multiline arrays unsupported")
-            table[key] = [
-                _parse_scalar(t, where) for t in _split_array(val[1:-1], where)
-            ]
-        else:
-            table[key] = _parse_scalar(val, where)
-    return root
-
-
 def loads_toml(text: str) -> Dict[str, Any]:
-    """Parse spec TOML — via ``tomllib`` when available, else the subset
-    parser (both accept everything `dumps_toml` emits)."""
-    if _tomllib is not None:
-        return _tomllib.loads(text)
-    return _parse_toml_subset(text)
+    """Parse spec TOML (everything `dumps_toml` emits)."""
+    return tomllib.loads(text)
 
 
 def _fmt_scalar(v: Any) -> str:

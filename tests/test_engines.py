@@ -174,3 +174,31 @@ def test_kernel_prepare_memoized_per_csp():
     pk1, _, _ = ops.prepare_packed(csp)
     pk2, _, _ = ops.prepare_packed(csp)
     assert pk1[0] is pk2[0]
+
+
+# --- interpret or compile: one decision, from the backend --------------------
+
+
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    import jax
+
+    assert ops.interpret_mode() is True  # the tests run on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode() is False
+    assert get_engine("pallas_packed").interpret is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode()
+
+
+@pytest.mark.parametrize("name", ["pallas_dense", "pallas_packed"])
+def test_service_ladder_stepped_rung_inherits_the_engine_mode(name):
+    from repro.service import SolverService
+
+    primary = get_engine(name)
+    primary.interpret = False  # as constructed on a TPU
+    ladder = SolverService._build_ladder(primary)
+    assert [getattr(e, "name", None) for e in ladder] == [name, name, "einsum"]
+    stepped = ladder[1]
+    assert stepped.fixpoint == "stepped" and not stepped.fused_fixpoint
+    assert stepped.interpret is False

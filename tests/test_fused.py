@@ -1,13 +1,12 @@
 """Fused in-kernel fixpoint validation — interpret-mode parity sweeps.
 
-The fused kernels (`dense_fixpoint_stacked` / `packed_fixpoint_stacked`) run
-the WHOLE AC recurrence inside one `pl.pallas_call`; the stepped path
+The fused kernel (`rtac_support.fixpoint_rows`, dense and packed encodings)
+runs the WHOLE AC recurrence inside one `pl.pallas_call`; the stepped path
 (`rtac.enforce_rows_generic` around per-iteration revise kernels) is the
 oracle. Parity must be bit-identical — domains, verdicts, AND per-row
-recurrence counts — on odd/padded shapes (n, d, W not multiples of the block
-sizes), across every schedule knob (instance tiling block_r, sweep tiles
-block_rx/block_ry, loop-nest order "xy"/"yx"), because the autotuner is free
-to pick any of them. Also covers the `kernels/ref.py` single-revise oracle
+recurrence counts — on odd/padded shapes (n, d, W not multiples of the
+padding blocks), across every schedule the autotuner may pick (the instance
+tiling ``block_r``). Also covers the `kernels/ref.py` single-revise oracle
 chained on the host, engine/solve_many-level fused-vs-stepped equality, and
 the autotune cache round-trip.
 
@@ -25,12 +24,13 @@ from repro.core import random_csp, rtac
 from repro.core.engine import pad_changed, pad_dom
 from repro.core.search import solve_many
 from repro.engines import get_engine
-from repro.kernels import autotune, ops
+from repro.kernels import autotune, ops, rtac_support
 from repro.kernels.ref import revise_ref
 
 pytestmark = pytest.mark.pallas
 
-# (n_vars, dom_size, block_rx, block_ry) — odd n/d so every case exercises the
+# (n_vars, dom_size, block_rx, block_ry) — the variable axis pads to a
+# multiple of max(block_rx, block_ry); odd n/d so every case exercises the
 # padding boundary; (24, 33) is multi-word bitpack, (12, 64) exactly 2 words
 SHAPE_SWEEP = [
     (4, 3, 4, 4),
@@ -40,17 +40,16 @@ SHAPE_SWEEP = [
     (12, 64, 4, 4),
 ]
 
-#: fused-schedule knobs every case sweeps: (block_r, sweep). 5 rows means
-#: block_r=1 tiles exactly and block_r=8 exercises `effective_block_r`'s
-#: fallback through the padded round width.
-SCHEDULES = [(1, "xy"), (1, "yx"), (4, "xy"), (4, "yx")]
+#: fused schedules every case sweeps (block_r). 4 rows means 1, 2 and 4 tile
+#: exactly and 8 exercises `effective_block_r`'s fallback.
+SCHEDULES = [1, 2, 4, 8]
 
 
 def _rows_fixture(n, d, brx, bry, prepare):
     """3 networks, 4 rows via idx [0,1,2,1]; row 3 starts near wipeout and the
     seed mixes root (all-changed) with sparse patterns."""
     csps = [random_csp(n, d, 0.7, 0.5, seed=40 + i) for i in range(3)]
-    prepared = [prepare(c, brx, bry) for c in csps]
+    prepared = [prepare(c, max(brx, bry)) for c in csps]
     dims = prepared[0][2]
     tables = (
         jnp.stack([p[0][0] for p in prepared]),
@@ -70,75 +69,40 @@ def _stepped_oracle(tables, dims, idx, dom_p, ch_p, rows_fn):
     )
 
 
-@pytest.mark.parametrize("n,d,brx,bry", SHAPE_SWEEP)
-def test_dense_fused_bit_identical_to_stepped(n, d, brx, bry):
-    csps, tables, (n_p, d_p), idx, doms, changed = _rows_fixture(
-        n, d, brx, bry, ops.prepare_dense
-    )
+def _fused_matches_stepped(kind, n, d, brx, bry):
+    prepare = ops.prepare_dense if kind == "dense" else ops.prepare_packed
+    csps, tables, dims, idx, doms, changed = _rows_fixture(n, d, brx, bry, prepare)
+    n_p, d_p = dims[0], dims[1]
     r = len(idx)
     dom_p = pad_dom(jnp.asarray(doms), n_p, d_p)
     ch_p = pad_changed(jnp.asarray(changed), n, n_p, batch=(r,))
-    ref = _stepped_oracle(
-        tables, (n_p, d_p), idx, dom_p, ch_p,
-        ops._dense_rows_fn(n_p, d_p, brx, bry, True),
-    )
-    from repro.kernels import rtac_support
-
-    for block_r, sweep in SCHEDULES:
+    ref = _stepped_oracle(tables, dims, idx, dom_p, ch_p, ops.rows_fn(kind, True))
+    operands = ops.kernel_operands((tables[0][idx], tables[1][idx]), dom_p, ch_p)
+    for block_r in SCHEDULES:
         br = autotune.effective_block_r(block_r, r)
-        got_dom, got_cons, got_k = rtac_support.dense_fixpoint_stacked(
-            tables[0][idx],
-            dom_p.astype(jnp.uint8).reshape(r, 1, n_p * d_p),
-            ch_p.astype(jnp.uint8).reshape(r, 1, n_p),
-            tables[1][idx],
-            d=d_p, block_r=br, block_rx=brx, block_ry=bry, sweep=sweep,
+        got_dom, got_cons, got_k = rtac_support.fixpoint_rows(
+            *operands, encoding=kind, d=d_p, block_r=br, interpret=True
         )
         np.testing.assert_array_equal(
             np.asarray(got_dom).reshape(r, n_p, d_p).astype(bool),
             np.asarray(ref.dom),
         )
         np.testing.assert_array_equal(
-            np.asarray(got_cons)[:, 0].astype(bool), np.asarray(ref.consistent)
+            np.asarray(got_cons)[:, 0, 0].astype(bool), np.asarray(ref.consistent)
         )
         np.testing.assert_array_equal(
-            np.asarray(got_k)[:, 0], np.asarray(ref.n_recurrences)
+            np.asarray(got_k)[:, 0, 0], np.asarray(ref.n_recurrences)
         )
+
+
+@pytest.mark.parametrize("n,d,brx,bry", SHAPE_SWEEP)
+def test_dense_fused_bit_identical_to_stepped(n, d, brx, bry):
+    _fused_matches_stepped("dense", n, d, brx, bry)
 
 
 @pytest.mark.parametrize("n,d,brx,bry", SHAPE_SWEEP)
 def test_packed_fused_bit_identical_to_stepped(n, d, brx, bry):
-    csps, tables, (n_p, d_p, w), idx, doms, changed = _rows_fixture(
-        n, d, brx, bry, ops.prepare_packed
-    )
-    r = len(idx)
-    dom_p = pad_dom(jnp.asarray(doms), n_p, d_p)
-    ch_p = pad_changed(jnp.asarray(changed), n, n_p, batch=(r,))
-    ref = _stepped_oracle(
-        tables, (n_p, d_p, w), idx, dom_p, ch_p,
-        ops._packed_rows_fn(n_p, d_p, w, brx, bry, True),
-    )
-    from repro.kernels import bitpack_support, ref as kref
-
-    dom_words = kref.pack_bits_ref(dom_p).reshape(r, 1, n_p * w)
-    for block_r, sweep in SCHEDULES:
-        br = autotune.effective_block_r(block_r, r)
-        got_dom, got_cons, got_k = bitpack_support.packed_fixpoint_stacked(
-            tables[0][idx],
-            dom_words,
-            ch_p.astype(jnp.uint8).reshape(r, 1, n_p),
-            tables[1][idx],
-            d=d_p, w=w, block_r=br, block_rx=brx, block_ry=bry, sweep=sweep,
-        )
-        np.testing.assert_array_equal(
-            np.asarray(got_dom).reshape(r, n_p, d_p).astype(bool),
-            np.asarray(ref.dom),
-        )
-        np.testing.assert_array_equal(
-            np.asarray(got_cons)[:, 0].astype(bool), np.asarray(ref.consistent)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(got_k)[:, 0], np.asarray(ref.n_recurrences)
-        )
+    _fused_matches_stepped("packed", n, d, brx, bry)
 
 
 @pytest.mark.parametrize("n,d,brx,bry", [(10, 6, 8, 8), (24, 33, 8, 8)])
@@ -152,7 +116,7 @@ def test_fused_rows_fn_matches_ref_oracle_chain(n, d, brx, bry):
     r = len(idx)
     dom_p = pad_dom(jnp.asarray(doms), n_p, d_p)
     ch_p = pad_changed(jnp.asarray(changed), n, n_p, batch=(r,))
-    fused = ops._packed_fixpoint_rows_fn(n_p, d_p, w, brx, bry, True)(
+    fused = ops.fixpoint_rows_fn("packed", True)(
         (tables[0][idx], tables[1][idx]), dom_p, ch_p
     )
     for row, j in enumerate(idx):
@@ -236,16 +200,17 @@ def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
     autotune.reset()
     try:
         cfg = autotune.tune("packed", 16, 8, r=2, repeats=1, path=path)
-        key = autotune.bucket_key("packed", 16, 8, 1, 2)
+        key = autotune.bucket_key("packed", 16, 8, 2)
+        assert key.startswith(autotune.device_kind() + "/")
         payload = json.loads(path.read_text())
         assert payload["schema"] == autotune.SCHEMA
         assert payload["configs"][key] == cfg.to_dict()
         # a fresh in-memory table reloads the winner from disk
         autotune.reset()
-        got = autotune.get_config("packed", 16, 8, 1, 2, 8, 8)
+        got = autotune.get_config("packed", 16, 8, 2)
         assert got == cfg
         # ensure_tuned is a pure cache hit now — no re-timing
-        assert autotune.ensure_tuned("packed", 16, 8, 1, 2, path=path) == cfg
+        assert autotune.ensure_tuned("packed", 16, 8, 2, path=path) == cfg
     finally:
         autotune.reset()
 
@@ -254,17 +219,24 @@ def test_autotune_untuned_bucket_falls_back_to_engine_defaults(tmp_path, monkeyp
     monkeypatch.setenv(autotune.CACHE_ENV, str(tmp_path / "missing.json"))
     autotune.reset()
     try:
-        cfg = autotune.get_config("dense", 16, 8, 0, 4, 4, 8)
-        assert (cfg.block_rx, cfg.block_ry, cfg.sweep) == (4, 8, "xy")
+        cfg = autotune.get_config("dense", 16, 8, 4)
+        assert cfg.block_r == rtac_support.max_block_r("dense", 16, 8) == 8
+        assert autotune.fused_block_r("dense", 16, 8, 6) == 6
     finally:
         autotune.reset()
 
 
 def test_autotune_sanitizes_stale_tiles_and_block_r():
-    # a cached schedule whose tiles no longer divide n_p must fall back
-    stale = autotune.TuneConfig(block_r=8, block_rx=5, block_ry=16, sweep="yx")
-    fixed = autotune._sanitize(stale, n_p=16, block_rx=8, block_ry=8)
-    assert (fixed.block_rx, fixed.block_ry, fixed.sweep) == (8, 16, "yx")
+    # a cached block_r over the VMEM budget is clamped to it; every candidate
+    # fits the budget, so every candidate compiles
+    stale = autotune.TuneConfig(block_r=8)
+    assert rtac_support.max_block_r("dense", 128, 32) < 8
+    fixed = autotune._sanitize(stale, "dense", 128, 32)
+    assert fixed.block_r == rtac_support.max_block_r("dense", 128, 32)
+    assert all(
+        c.block_r <= fixed.block_r
+        for c in autotune.candidate_configs("dense", 128, 32, 64)
+    )
     assert autotune.effective_block_r(8, 6) == 6
     assert autotune.effective_block_r(8, 5) == 5
     assert autotune.effective_block_r(4, 6) == 3

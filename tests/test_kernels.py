@@ -29,7 +29,8 @@ from repro.kernels.ref import (
 pytestmark = pytest.mark.pallas
 
 SHAPE_SWEEP = [
-    # (n_vars, dom_size, block_rx, block_ry)
+    # (n_vars, dom_size, block_rx, block_ry): the variable axis pads to a
+    # multiple of max(block_rx, block_ry)
     (4, 3, 4, 4),
     (8, 5, 8, 8),
     (10, 6, 8, 8),
@@ -53,8 +54,8 @@ def _changed_patterns(n, seed):
 @pytest.mark.parametrize("n,d,brx,bry", SHAPE_SWEEP)
 def test_dense_kernel_matches_oracle(n, d, brx, bry):
     csp = random_csp(n, d, density=0.6, tightness=0.4, seed=n * 100 + d)
-    net, dom_p, (n_p, d_p) = ops.prepare_dense(csp, brx, bry)
-    rf = ops._dense_revise_fn(n_p, d_p, brx, bry, True)
+    net, dom_p, (n_p, d_p) = ops.prepare_dense(csp, max(brx, bry))
+    rf = ops.revise_fn("dense", True)
     for changed in _changed_patterns(n, seed=d):
         ch = jnp.asarray(changed)
         oracle = revise_ref(csp.cons, csp.mask, csp.dom, ch)
@@ -65,8 +66,8 @@ def test_dense_kernel_matches_oracle(n, d, brx, bry):
 @pytest.mark.parametrize("n,d,brx,bry", SHAPE_SWEEP)
 def test_packed_kernel_matches_oracle(n, d, brx, bry):
     csp = random_csp(n, d, density=0.6, tightness=0.4, seed=n * 100 + d)
-    net, dom_p, (n_p, d_p, w) = ops.prepare_packed(csp, brx, bry)
-    rf = ops._packed_revise_fn(n_p, d_p, w, brx, bry, True)
+    net, dom_p, (n_p, d_p, w) = ops.prepare_packed(csp, max(brx, bry))
+    rf = ops.revise_fn("packed", True)
     for changed in _changed_patterns(n, seed=d):
         ch = jnp.asarray(changed)
         oracle = revise_ref(csp.cons, csp.mask, csp.dom, ch)
@@ -87,7 +88,7 @@ STACK_SWEEP = [
 def _stacked_fixture(n, d, brx, bry, prepare):
     """3 networks, 5 rows via idx [2,0,1,2,0], mixed changed patterns."""
     csps = [random_csp(n, d, 0.6, 0.4, seed=300 + i) for i in range(3)]
-    prepared = [prepare(c, brx, bry) for c in csps]
+    prepared = [prepare(c, max(brx, bry)) for c in csps]
     dims = prepared[0][2]
     cons_g = jnp.stack([p[0][0] for p in prepared])
     mask_g = jnp.stack([p[0][1] for p in prepared])
@@ -104,7 +105,7 @@ def test_stacked_dense_rows_match_oracle(n, d, brx, bry):
     csps, (cons_g, mask_g), (n_p, d_p), idx, doms, changed = _stacked_fixture(
         n, d, brx, bry, ops.prepare_dense
     )
-    rf = ops._dense_rows_fn(n_p, d_p, brx, bry, True)
+    rf = ops.rows_fn("dense", True)
     dom_p = pad_dom(jnp.asarray(doms), n_p, d_p)
     ch_p = pad_changed(jnp.asarray(changed), n, n_p, batch=(len(idx),))
     got = np.asarray(rf((cons_g[idx], mask_g[idx]), dom_p, ch_p))
@@ -120,7 +121,7 @@ def test_stacked_packed_rows_match_oracle(n, d, brx, bry):
     csps, (cons_g, mask_g), (n_p, d_p, w), idx, doms, changed = _stacked_fixture(
         n, d, brx, bry, ops.prepare_packed
     )
-    rf = ops._packed_rows_fn(n_p, d_p, w, brx, bry, True)
+    rf = ops.rows_fn("packed", True)
     dom_p = pad_dom(jnp.asarray(doms), n_p, d_p)
     ch_p = pad_changed(jnp.asarray(changed), n, n_p, batch=(len(idx),))
     got = np.asarray(rf((cons_g[idx], mask_g[idx]), dom_p, ch_p))
@@ -135,15 +136,15 @@ def test_enforce_rows_generic_matches_solo_recurrence_counts():
     """The stacked fixpoint freezes converged/wiped-out rows: per-row domains,
     verdicts AND recurrence counts equal solo `enforce_generic` runs even
     though the while_loop runs until the slowest row converges."""
-    n, d, brx, bry = 10, 6, 8, 8
+    n, d = 10, 6
     csps = [random_csp(n, d, 0.7, 0.5, seed=40 + i) for i in range(3)]
-    prepared = [ops.prepare_packed(c, brx, bry) for c in csps]
+    prepared = [ops.prepare_packed(c) for c in csps]
     n_p, d_p, w = prepared[0][2]
     tables = (
         jnp.stack([p[0][0] for p in prepared]),
         jnp.stack([p[0][1] for p in prepared]),
     )
-    rf = ops._packed_rows_fn(n_p, d_p, w, brx, bry, True)
+    rf = ops.rows_fn("packed", True)
     idx = np.array([0, 1, 2, 1], np.int32)
     doms = np.stack([np.asarray(csps[j].dom) for j in idx])
     doms[3, 0, 1:] = False  # a row that starts near wipeout
@@ -161,7 +162,7 @@ def test_enforce_rows_generic_matches_solo_recurrence_counts():
             prepared[j][0],
             pad_dom(jnp.asarray(doms[row]), n_p, d_p),
             pad_changed(None, n, n_p),
-            revise_fn=ops._packed_revise_fn(n_p, d_p, w, brx, bry, True),
+            revise_fn=ops.revise_fn("packed", True),
         )
         assert bool(np.asarray(res.consistent)[row]) == bool(np.asarray(solo.consistent))
         assert int(np.asarray(res.n_recurrences)[row]) == int(np.asarray(solo.n_recurrences))

@@ -14,7 +14,7 @@ from repro.sweeps import (
     run_spec,
     sweep_dir,
 )
-from repro.sweeps.spec import _parse_toml_subset, available_specs
+from repro.sweeps.spec import available_specs
 
 
 def _tiny_spec(**overrides):
@@ -42,18 +42,17 @@ def _tiny_spec(**overrides):
 
 
 def test_toml_round_trip_both_parsers():
-    """dumps_toml output parses identically through tomllib (when present)
-    and the fallback subset parser — the 3.10 CI leg uses the fallback."""
+    """dumps_toml output parses back through tomllib to the same spec, and
+    emitting the parsed document again is a fixed point."""
     spec = _tiny_spec()
     text = spec.to_toml()
-    via_default = loads_toml(text)          # tomllib on 3.11+, fallback on 3.10
-    via_fallback = _parse_toml_subset(text)  # always the fallback
-    assert via_default == via_fallback
-    assert SweepSpec.from_doc(via_fallback) == spec
+    doc = loads_toml(text)
+    assert SweepSpec.from_doc(doc) == spec
+    assert SweepSpec.from_doc(doc).to_toml() == text
 
 
 def test_toml_subset_scalars_arrays_comments():
-    doc = _parse_toml_subset(
+    doc = loads_toml(
         '\n'.join([
             '# leading comment',
             'name = "x"  # trailing comment',
@@ -79,7 +78,7 @@ def test_toml_subset_scalars_arrays_comments():
 def test_toml_subset_rejects_garbage():
     for bad in ("just words", "[unclosed", 'k = "no end', "k ="):
         with pytest.raises(ValueError):
-            _parse_toml_subset(bad)
+            loads_toml(bad)
 
 
 def test_committed_specs_load_and_expand():

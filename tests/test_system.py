@@ -1,5 +1,9 @@
 """End-to-end behaviour tests for the paper's system (RTAC pipeline)."""
 
+from pathlib import Path
+
+import pytest
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -90,7 +94,35 @@ def test_sharded_enforcer_multidevice_subprocess():
         """
     )
     out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True, cwd="/root/repo",
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=str(Path(__file__).resolve().parents[1]),
         timeout=600,
     )
     assert "SHARDED_OK" in out.stdout, out.stderr[-2000:]
+
+
+@pytest.mark.parametrize("placed", [False, True])
+def test_compile_cache_placement_subprocess(tmp_path, placed):
+    """The entry points' compile cache: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set (nothing set in code), else the fixed ``<checkout>/.jax_cache``."""
+    import os
+    import subprocess
+    import sys
+
+    root = Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=str(root / "src"), JAX_PLATFORMS="cpu")
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
+    code = (
+        "import jax; from repro.launch import compile_cache; "
+        "print(compile_cache.enable()); print(jax.config.jax_compilation_cache_dir)"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, env=env,
+        cwd=str(tmp_path), timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    returned, configured = out.stdout.split()
+    want = str(tmp_path / "cc") if placed else str(root / ".jax_cache")
+    assert returned == configured == want
