@@ -534,7 +534,9 @@ class _PendingFrontierRound:
         self._r = r
 
     def resolve(self) -> RoundMeta:
-        cons, k, bvar, vrow, *alt = jax.device_get(self._meta)
+        # the host's one wait on the device in a round
+        with obs.span("round.wait", cat="driver"):
+            cons, k, bvar, vrow, *alt = jax.device_get(self._meta)
         self._table._count_d2h(cons, k, bvar, vrow, *alt)
         r = self._r
         handles: List[Optional[int]] = []

@@ -1375,41 +1375,42 @@ def solve_many(
             _fill_rounds_histogram(telemetry, stats)
         return sols, stats
 
-    prepared = eng.prepare_many(csps)  # the ONLY preparation in the whole run
-    # speculative members multiply the worst-case live rows per instance
-    n_eff = len(csps) * (1 + max(0, split_budget) + max(0, portfolio))
-    if eng.device_frontier:
-        networks = eng.frontier_networks(prepared)
-        store = eng.open_frontier(
-            lambda: networks, prepared.n_vars, prepared.dom_size,
-            # presize for the worst case a DFS can hold live (every level keeps
-            # its node + unvisited siblings): growth mid-run would recompile
-            # the fused step for every round shape, and rows are n·d bools —
-            # cheap enough that oversizing beats recompiling
-            capacity=frontier_capacity(n_eff, prepared.n_vars, prepared.dom_size),
-        )
-    else:
-        # host store over the stacked/host-routed enforce_many dispatch; pad
-        # rounds only when the dispatch is one jit-shaped stacked program
-        store = HostFrontierStore(
-            prepared.n_vars, prepared.enforce_many, pad_rounds=eng.stacked_many
-        )
-    driver = LockstepDriver(store, prepared.n_vars, count_unit=eng.count_unit)
-    all_stats = [
-        driver.admit_group(
-            i,
-            csp,
-            idx=i,
-            split_budget=split_budget,
-            portfolio=portfolio,
-            portfolio_seed=portfolio_seed + i,
-            supports_batch=eng.supports_batch,
-            batched_children=batched_children,
-            max_assignments=max_assignments,
-            collect_stats=collect_stats,
-        )
-        for i, csp in enumerate(csps)
-    ]
+    with obs.span("many.prepare", cat="driver", n=len(csps)):
+        prepared = eng.prepare_many(csps)  # the ONLY preparation in the whole run
+        # speculative members multiply the worst-case live rows per instance
+        n_eff = len(csps) * (1 + max(0, split_budget) + max(0, portfolio))
+        if eng.device_frontier:
+            networks = eng.frontier_networks(prepared)
+            store = eng.open_frontier(
+                lambda: networks, prepared.n_vars, prepared.dom_size,
+                # presize for the worst case a DFS can hold live (every level keeps
+                # its node + unvisited siblings): growth mid-run would recompile
+                # the fused step for every round shape, and rows are n·d bools —
+                # cheap enough that oversizing beats recompiling
+                capacity=frontier_capacity(n_eff, prepared.n_vars, prepared.dom_size),
+            )
+        else:
+            # host store over the stacked/host-routed enforce_many dispatch; pad
+            # rounds only when the dispatch is one jit-shaped stacked program
+            store = HostFrontierStore(
+                prepared.n_vars, prepared.enforce_many, pad_rounds=eng.stacked_many
+            )
+        driver = LockstepDriver(store, prepared.n_vars, count_unit=eng.count_unit)
+        all_stats = [
+            driver.admit_group(
+                i,
+                csp,
+                idx=i,
+                split_budget=split_budget,
+                portfolio=portfolio,
+                portfolio_seed=portfolio_seed + i,
+                supports_batch=eng.supports_batch,
+                batched_children=batched_children,
+                max_assignments=max_assignments,
+                collect_stats=collect_stats,
+            )
+            for i, csp in enumerate(csps)
+        ]
     sols: List[Optional[List[int]]] = [None] * len(csps)
     while driver.has_work:
         for i, (sol, _st) in driver.round().items():

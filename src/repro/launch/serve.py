@@ -11,7 +11,9 @@ under load is real), and prints sustained throughput plus tail latency.
 With ``--trace-out run.json`` (or ``REPRO_TRACE=1`` in the environment) the
 replay runs under the `repro.obs` tracer and drops the full run payload plus
 a ``run.perfetto.json`` timeline next to it — load the latter in
-ui.perfetto.dev, or ``python -m repro.obs summarize run.json``.
+ui.perfetto.dev, or ``python -m repro.obs summarize run.json``. While the
+tracer is on, its spans also land in any `jax.profiler` trace taken
+meanwhile, so an operator's profile shows them beside the device's ops.
 
 With ``--faults RECIPE`` (or ``REPRO_FAULTS`` in the environment) the replay
 runs under seeded fault injection — the chaos drill CI's chaos-smoke leg

@@ -8,6 +8,9 @@ One import surface for the three pieces (DESIGN.md §10):
   enabled by `enable()` or ``REPRO_TRACE=1``; ``timing="fenced"``
   (``REPRO_TRACE_TIMING=fenced``) opts into `jax.block_until_ready`
   fencing so spans measure device completion instead of async launch.
+  While on, each span also lands in any `jax.profiler` trace taken
+  meanwhile (a `TraceAnnotation` of its name), beside the device's ops;
+  ``SPANS`` declares every name the program opens.
 - **registry** (`obs.REGISTRY`, `obs.counter_add` / `gauge_set` /
   `observe`): always-on named counters/gauges/histograms every subsystem
   publishes into; `snapshot()` is the one ``repro-obs/v1`` dict the
@@ -16,8 +19,8 @@ One import surface for the three pieces (DESIGN.md §10):
   run dumps and Chrome-trace/Perfetto timelines.
 
 This package imports only the standard library + numpy (jax is deferred
-inside `fence`), so instrumented core modules can import it without cycles
-or import-time cost.
+inside `enable` and `fence`), so instrumented core modules can import it
+without cycles or import-time cost.
 """
 
 from . import export, registry, tracing  # noqa: F401  (submodule access)
@@ -36,6 +39,7 @@ from .registry import (
     summarize,
 )
 from .tracing import (
+    SPANS,
     Span,
     Tracer,
     disable,
@@ -50,7 +54,7 @@ from .tracing import (
 )
 
 __all__ = [
-    "REGISTRY", "SCHEMA", "Registry", "RegistryScope", "Span", "Tracer",
+    "REGISTRY", "SCHEMA", "SPANS", "Registry", "RegistryScope", "Span", "Tracer",
     "child_coverage", "chrome_trace", "counter_add", "disable", "dump_run",
     "enable", "enable_from_env", "enabled", "fence", "gauge_set",
     "get_tracer", "load_run", "mean", "now", "observe", "percentile",

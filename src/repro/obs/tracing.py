@@ -7,6 +7,11 @@ The tracing contract (DESIGN.md §10):
   shared null context manager — the off-path cost of an instrumentation
   point is a global read plus a no-op ``with``. No span objects, no clock
   reads, no ring writes.
+- **One clock with the profiler.** While a tracer is enabled, every `span()`
+  also opens a `jax.profiler.TraceAnnotation` of its name for its lifetime,
+  so a `jax.profiler` trace taken meanwhile holds the span on its host
+  plane, on the clock its device planes use. The annotation is resolved in
+  `enable()`, never at import; tracing off makes none.
 - **Bounded memory.** Spans land in a ``deque(maxlen=capacity)`` ring; a
   long-lived service overwrites its oldest spans instead of growing, and
   the ``dropped`` counter says how many rolled off.
@@ -41,6 +46,14 @@ TIMING_ENV = "REPRO_TRACE_TIMING"
 RING_ENV = "REPRO_TRACE_RING"
 DEFAULT_RING = 65_536
 TIMING_MODES = ("async", "fenced")
+#: every name the program opens with `span()`: what a profile's reader takes
+#: for a program span on the host plane (any other host event is JAX's own)
+SPANS = frozenset({
+    "autotune.search", "cache.lookup", "driver.round", "frontier.step",
+    "group.cancel", "group.spawn", "kernel.launch", "many.prepare",
+    "round.resolve", "round.wait", "service.admit", "service.recover",
+    "service.step", "slot.install",
+})
 
 
 class Span:
@@ -171,7 +184,7 @@ class _SpanCtx:
     returns the `Span` so call sites can attach result args
     (``s.args["hit"] = True``) before exit."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_track", "_args", "_span")
+    __slots__ = ("_tracer", "_name", "_cat", "_track", "_args", "_span", "_note")
 
     def __init__(self, tracer: Tracer, name: str, cat: str, track: str,
                  args: Dict[str, Any]):
@@ -181,8 +194,11 @@ class _SpanCtx:
         self._track = track
         self._args = args
         self._span: Optional[Span] = None
+        self._note = None
 
     def __enter__(self) -> Span:
+        self._note = _ANNOTATION(self._name)
+        self._note.__enter__()
         self._span = self._tracer.begin(self._name, self._cat, self._track, self._args)
         return self._span
 
@@ -191,12 +207,15 @@ class _SpanCtx:
         # must not strand the stack
         if self._span is not None:
             self._tracer.end(self._span)
+            self._note.__exit__(None, None, None)
         return False
 
 
 # --- the module-level tracer (what the instrumentation points talk to) ------
 
 _TRACER: Optional[Tracer] = None
+#: `jax.profiler.TraceAnnotation`, bound by `enable()` (never at import)
+_ANNOTATION = None
 
 
 def enabled() -> bool:
@@ -208,8 +227,12 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def enable(capacity: int = DEFAULT_RING, timing: str = "async") -> Tracer:
-    """Install a fresh tracer (replacing any prior one) and return it."""
-    global _TRACER
+    """Install a fresh tracer (replacing any prior one) and return it. Its
+    spans also land in any `jax.profiler` trace taken while it is on."""
+    from jax.profiler import TraceAnnotation  # deferred: obs must import without jax
+
+    global _TRACER, _ANNOTATION
+    _ANNOTATION = TraceAnnotation
     _TRACER = Tracer(capacity=capacity, timing=timing)
     return _TRACER
 

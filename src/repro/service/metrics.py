@@ -17,7 +17,9 @@ All reductions route through the shared `repro.obs.registry` helpers
 ONE place; ``window=1`` degenerates to last-sample metrics but stays finite.
 Every ``record_*`` call also publishes into the central obs registry
 (``service.*`` counters/histograms), so a process-wide `obs.snapshot()`
-carries the same figures without holding a service reference.
+carries the same figures without holding a service reference; rounds are
+the exception, since the driver publishes them itself (``driver.rounds``,
+``driver.rows``, the ``driver.round`` span).
 """
 
 from __future__ import annotations
@@ -129,9 +131,6 @@ class ServiceMetrics:
         self.round_searches.append(searches)
         self.round_seconds.append(seconds)
         self.round_launches.append(launches)
-        obs.counter_add("service.rounds")
-        obs.counter_add("service.rows_dispatched", rows)
-        obs.observe("service.round_ms", 1e3 * seconds)
 
     def record_request_rows(self, rows: int, members: int, cancelled: int) -> None:
         """File one retired request's lifetime row consumption and speculation

@@ -15,8 +15,11 @@ The load-bearing claims:
   ``window=1``, via the one shared percentile/mean implementation.
 """
 
+import glob
 import json
 import math
+import re
+from pathlib import Path
 
 import jax
 import pytest
@@ -27,6 +30,8 @@ from repro.problems import generate, generate_batch
 from repro.service import FastForwardClock, SolverService, poisson_trace, replay
 from repro.service.buckets import speculative_budget
 from repro.service.metrics import ServiceMetrics
+
+SRC = Path(__file__).resolve().parents[1] / "src" / "repro"
 
 
 @pytest.fixture(autouse=True)
@@ -135,6 +140,121 @@ def test_tracer_rejects_bad_config():
         obs.Tracer(timing="blocking")
     with pytest.raises(ValueError):
         obs.Tracer(capacity=0)
+
+
+# --- one clock with the profiler ---------------------------------------------
+
+
+def _host_events(log_dir, names):
+    """``{name: [(start_ns, end_ns, line)]}`` of a profile's host planes."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        out.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns, line.name))
+    return out
+
+
+def test_spans_land_in_a_profiler_trace_with_their_nesting(tmp_path):
+    names = {"driver.round", "round.resolve", "round.wait", "frontier.step"}
+    obs.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("driver.round"):
+            with obs.span("round.resolve"):
+                with obs.span("round.wait"):
+                    jax.device_get(jax.numpy.arange(4) + 1)
+            with obs.span("frontier.step"):
+                pass
+    tracer = obs.disable()
+    got = _host_events(tmp_path, names)
+    assert {k: len(v) for k, v in got.items()} == dict.fromkeys(names, 1)
+    (rnd,), (res,), (wait,), (step,) = (got[n] for n in
+                                        ("driver.round", "round.resolve", "round.wait",
+                                         "frontier.step"))
+    assert len({rnd[2], res[2], wait[2], step[2]}) == 1  # one thread's line
+    assert rnd[0] <= res[0] <= wait[0] and wait[1] <= res[1] <= step[0] <= step[1] <= rnd[1]
+    # the ring holds the same spans
+    assert {s["name"] for s in tracer.snapshot_spans()} == names
+
+
+def test_no_trace_annotation_is_made_while_tracing_is_off(monkeypatch):
+    made = []
+
+    class Counting:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    with obs.span("driver.round"):
+        pass
+    solve_many(generate_batch("model_rb", 2, n=8, hardness=1.0, seed=4), engine="einsum")
+    assert made == []
+    # on, the tracer resolves the annotation at enable() and opens one a span
+    obs.enable()
+    with obs.span("driver.round"), obs.span("round.wait"):
+        pass
+    assert made == ["driver.round", "round.wait"]
+
+
+def test_a_bare_tracer_opens_no_annotation(monkeypatch):
+    """The recording core (`Tracer.begin` / `end`) keeps to its ring; only a
+    `span()` of the tracer `enable()` installed opens the annotation."""
+    made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", lambda name: made.append(name))
+    tracer = obs.Tracer()
+    tracer.end(tracer.begin("driver.round"))
+    assert made == [] and [s["name"] for s in tracer.snapshot_spans()] == ["driver.round"]
+    obs.enable()
+    assert obs.tracing._ANNOTATION is jax.profiler.TraceAnnotation
+
+
+def test_every_span_the_program_opens_is_declared():
+    opened = set()
+    for path in SRC.rglob("*.py"):
+        opened |= set(re.findall(r'obs\.span\(\s*"([^"]+)"', path.read_text()))
+    assert opened == obs.SPANS
+
+
+def test_round_wait_nests_in_the_resolve_of_a_device_frontier_round():
+    tracer = obs.enable()
+    solve_many(generate_batch("model_rb", 3, n=10, hardness=1.0, seed=2), engine="einsum")
+    spans = tracer.snapshot_spans()
+    by_sid = {s["sid"]: s for s in spans}
+    waits = [s for s in spans if s["name"] == "round.wait"]
+    assert waits and all(by_sid[s["parent"]]["name"] == "round.resolve" for s in waits)
+
+
+def test_solve_many_names_its_preparation():
+    tracer = obs.enable()
+    solve_many(generate_batch("model_rb", 3, n=10, hardness=1.0, seed=2), engine="einsum")
+    spans = tracer.snapshot_spans()
+    (prep,) = [s for s in spans if s["name"] == "many.prepare"]
+    rounds = [s for s in spans if s["name"] == "driver.round"]
+    assert prep["parent"] == 0 and prep["args"] == {"n": 3}
+    assert rounds and all(s["t0"] >= prep["t0"] + prep["dur"] for s in rounds)
+
+
+def test_rounds_publish_through_the_driver_alone():
+    """`ServiceMetrics` still counts rounds for its snapshot, but leaves the
+    registry to the driver's own ``driver.rounds`` / ``driver.rows``."""
+    m = ServiceMetrics()
+    m.record_round(rows=4, searches=2, seconds=0.01)
+    assert m.snapshot()["rows_dispatched"] == 4 and m.n_rounds == 1
+    snap = obs.snapshot()
+    assert not [k for part in ("counters", "histograms") for k in snap[part]
+                if k.startswith("service.")]
 
 
 # --- no semantic footprint: verdict parity across tracing modes --------------
