@@ -80,36 +80,56 @@ def pad_round_rows(arrays: Sequence[np.ndarray], r_p: int) -> List[np.ndarray]:
 
 
 def padded_shape(n: int, d: int, n_block: int, d_mult: int):
-    """The kernel-tile shape `pad_network` pads (n, d) to. The ONE place the
-    formula lives — engines that size slot tables without a CSP in hand
-    (`_open_stacked_slot_pool`) must agree with `pad_network` by construction,
-    not by convention."""
+    """The kernel-tile shape a network of caller shape (n, d) pads to. The ONE
+    place the formula lives — engines that size slot tables without a CSP in
+    hand (`_open_stacked_slot_pool`) agree with `kernels.ops.prepare_network`
+    by construction, not by convention."""
     return round_up(max(n, n_block), n_block), round_up(d, d_mult)
 
 
-def pad_network(csp: CSP, n_block: int, d_mult: int):
-    """Pad the *network* (cons, mask) to kernel tiles.
+def _pad_to(a, shape):
+    """Zero-pad ``a`` at the end of each axis to ``shape``: with numpy for a
+    host array (it stays on the host), with jax.numpy otherwise."""
+    if isinstance(a, np.ndarray):
+        out = np.zeros(shape, a.dtype)
+        out[tuple(slice(0, k) for k in a.shape)] = a
+        return out
+    return jnp.pad(a, [(0, s - k) for k, s in zip(a.shape, shape)])
 
-    Returns (cons, mask, n_p, d_p). Padded pairs are unconstrained
-    (mask False, cons zero blocks) so they never produce a violation.
-    """
-    n, d = csp.dom.shape
-    n_p, d_p = padded_shape(n, d, n_block, d_mult)
-    cons = jnp.pad(csp.cons, ((0, n_p - n), (0, n_p - n), (0, d_p - d), (0, d_p - d)))
-    mask = jnp.pad(csp.mask, ((0, n_p - n), (0, n_p - n)))
-    return cons, mask, n_p, d_p
+
+def pad_pairs(cons, mask, n_p: int, d_p: int):
+    """Pad a *network* — (n, n, d, d) cons and (n, n) mask — to (n_p, d_p).
+
+    Padded pairs are unconstrained (mask False, cons zero blocks) so they
+    never produce a violation. Host arrays pad on the host; device arrays and
+    tracers (inside a compiled install or encode) pad with jax.numpy."""
+    return _pad_to(cons, (n_p, n_p, d_p, d_p)), _pad_to(mask, (n_p, n_p))
 
 
 def pad_dom(dom: Array, n_p: int, d_p: int) -> Array:
     """Pad a domain tensor (..., n, d) -> (..., n_p, d_p).
 
     Padded variables get the singleton domain {0} (never empty → never trips
-    the wipeout check); padded values are False everywhere.
+    the wipeout check); padded values are False everywhere. A host array
+    pads on the host and stays numpy.
     """
     *batch, n, d = dom.shape
+    if isinstance(dom, np.ndarray):
+        out = _pad_to(dom, (*batch, n_p, d_p))
+        out[..., n:, 0] = True
+        return out
     dom = jnp.pad(dom, [(0, 0)] * len(batch) + [(0, 0), (0, d_p - d)])
     pad_rows = jnp.zeros((*batch, n_p - n, d_p), jnp.bool_).at[..., :, 0].set(True)
     return jnp.concatenate([dom, pad_rows], axis=-2)
+
+
+def pad_csp_to(csp: CSP, n_p: int, d_p: int) -> CSP:
+    """Pad a whole CSP — network and domain — to (n_p, d_p) under the §2
+    contract. A CSP of host arrays comes back as host arrays."""
+    if tuple(csp.dom.shape) == (n_p, d_p):
+        return csp
+    cons, mask = pad_pairs(csp.cons, csp.mask, n_p, d_p)
+    return CSP(cons=cons, mask=mask, dom=pad_dom(csp.dom, n_p, d_p))
 
 
 def pad_changed(changed0: Changed, n: int, n_p: int, batch: tuple = ()) -> Array:
@@ -266,11 +286,15 @@ class SlotPool:
             raise ValueError(f"slot {slot} already installed; release it first")
 
     def install(self, slot: int, csp: CSP) -> None:
-        """Compile ``csp``'s network into ``slot`` (must match the pool shape)."""
+        """Compile ``csp``'s network into ``slot``. The CSP may be smaller than
+        the pool's bucket shape: it is padded under the §2 contract on its
+        way in (on the host here, inside the install program on a
+        `StackedSlotPool`)."""
         self._check(slot, installing=True)
-        if tuple(csp.dom.shape) != (self.n_vars, self.dom_size):
+        n, d = csp.dom.shape
+        if n > self.n_vars or d > self.dom_size:
             raise ValueError(
-                f"install: csp shape {tuple(csp.dom.shape)} != pool bucket "
+                f"install: csp shape ({n}, {d}) does not fit pool bucket "
                 f"({self.n_vars}, {self.dom_size})"
             )
         faults.inject("slot.install", slot=slot)
@@ -284,7 +308,7 @@ class SlotPool:
         """Backend hook: build the slot's resident form. The generic pool keeps
         a `PreparedNetwork`; stacked pools write device tensors and return a
         truthy sentinel."""
-        return self.engine.prepare(csp)
+        return self.engine.prepare(pad_csp_to(csp, self.n_vars, self.dom_size))
 
     def release(self, slot: int) -> None:
         """Free a slot (its network may be overwritten by a later install)."""
@@ -319,27 +343,41 @@ class SlotPool:
         return occupied * self.engine.network_nbytes(self.n_vars, self.dom_size)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _slot_write(table, slot, value):
-    """In-place-ish slot update: with buffer donation XLA updates the resident
-    table without a copy (TPU/GPU; CPU falls back to a copy and warns once)."""
-    return table.at[slot].set(value)
+@functools.lru_cache(maxsize=None)
+def slot_install_program(encode: Callable, *args) -> Callable:
+    """The install program of a stacked slot table: ``install(tables, slot,
+    cons, mask) -> tables`` runs the traceable ``encode(cons, mask, *args)``
+    — pad the network to the table's shape and lay it out as one slot row
+    per table — and writes each row into ``slot``, all in ONE compiled
+    dispatch. ``cons``/``mask`` are the network as the CSP holds it (host
+    arrays are uploaded once, as they are). The tables are donated, so the
+    resident table is written in place, never copied (TPU/GPU; the CPU falls
+    back to a copy). Cached on (``encode``, ``args``), so pools of one
+    encoding and bucket share one program, compiled once per network shape."""
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def install(tables, slot, cons, mask):
+        row = encode(cons, mask, *args)
+        return jax.tree_util.tree_map(lambda t, v: t.at[slot].set(v), tables, row)
+
+    return install
 
 
 class StackedSlotPool(SlotPool):
     """A device-resident `SlotPool`: the networks live in *stacked* device
-    tensors (a pytree of ``(C, ...)`` tables), installs write one slot row via
-    a donated ``.at[slot].set``, and ``enforce_rows`` is ONE dispatch that
-    gathers each row's network from the tables — the open-world analogue of
-    `PreparedMany`'s stacked dispatch (DESIGN.md §7).
+    tensors (a pytree of ``(C, ...)`` tables), an install is one donated
+    compiled program that pads, encodes and writes one slot row, and
+    ``enforce_rows`` is ONE dispatch that gathers each row's network from the
+    tables — the open-world analogue of `PreparedMany`'s stacked dispatch
+    (DESIGN.md §7).
 
     The backend supplies its representation as three pieces:
 
     - ``tables``: the initial (zeroed) slot tables — ``(C, n, n, d, d)`` bool
-      cons for the einsum engines, ``(C, n_p·d_p, n_p·W)`` packed uint32 words
-      for `pallas_packed`;
-    - ``encode(csp)``: one network compiled into a matching pytree of slot
-      rows (the only O(n²d²) step, paid once per install);
+      cons for the einsum engines, ``(C, W, n_p, n_p·d_p)`` packed int32
+      words for `pallas_packed`;
+    - ``install``: the `slot_install_program` of its encoding at this
+      bucket shape (the only O(n²d²) step, paid once per distinct network);
     - ``dispatch(tables, doms, changed0, idx)``: the jitted gather + fixpoint
       over the whole round.
     """
@@ -353,23 +391,22 @@ class StackedSlotPool(SlotPool):
         dom_size: int,
         capacity: int,
         tables,
-        encode: Callable[[CSP], Any],
+        install: Callable,
         dispatch,
     ):
         super().__init__(engine, n_vars, dom_size, capacity)
         self._tables = tables
-        self._encode = encode
+        self._install = install
         self._dispatch = dispatch
 
     def _prepare_slot(self, slot: int, csp: CSP):
-        row = self._encode(csp)
-        s = jnp.int32(slot)
+        # the network's one upload: only host arrays move, as the CSP holds them
+        uploaded = sum(a.nbytes for a in (csp.cons, csp.mask) if isinstance(a, np.ndarray))
         with warnings.catch_warnings():
             # CPU backends can't honour donation; the copy fallback is correct.
             warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
-            self._tables = jax.tree_util.tree_map(
-                lambda t, v: _slot_write(t, s, jnp.asarray(v)), self._tables, row
-            )
+            self._tables = self._install(self._tables, np.int32(slot), csp.cons, csp.mask)
+        obs.REGISTRY.counter_add("slots.install_h2d_bytes", uploaded)
         return True  # occupancy sentinel; the network lives in the tables
 
     def grow(self, capacity: int) -> None:

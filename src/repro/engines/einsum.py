@@ -20,7 +20,9 @@ from repro.core.engine import (
     PreparedNetwork,
     StackedSlotPool,
     as_changed,
+    pad_pairs,
     resolve_instance_idx,
+    slot_install_program,
 )
 from repro.core.rtac import EnforceResult, SupportFn, einsum_support
 from . import register
@@ -57,9 +59,9 @@ def _stack_networks(csps: List[CSP]):
 
 
 def _open_einsum_pool(engine, n_vars, dom_size, capacity, round_dispatch):
-    """Shared einsum/full slot pool: unpadded bool (C, n, n, d, d) / (C, n, n)
-    tables; the round dispatch is the same jitted gather+vmap fixpoint as
-    `enforce_many`."""
+    """Shared einsum/full slot pool: bool (C, n, n, d, d) / (C, n, n) tables at
+    the bucket shape; the round dispatch is the same jitted gather+vmap
+    fixpoint as `enforce_many`."""
     n, d = n_vars, dom_size
     tables = (
         jnp.zeros((capacity, n, n, d, d), jnp.bool_),
@@ -73,7 +75,7 @@ def _open_einsum_pool(engine, n_vars, dom_size, capacity, round_dispatch):
 
     return StackedSlotPool(
         engine, n_vars, dom_size, capacity,
-        tables, encode=lambda csp: (csp.cons, csp.mask), dispatch=dispatch,
+        tables, install=slot_install_program(pad_pairs, n, d), dispatch=dispatch,
     )
 
 

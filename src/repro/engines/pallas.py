@@ -45,6 +45,7 @@ from repro.core.engine import (
     pad_changed,
     pad_dom,
     resolve_instance_idx,
+    slot_install_program,
 )
 from repro.core.rtac import EnforceResult, enforce_batch_generic, enforce_generic
 from repro.kernels import autotune, ops
@@ -173,7 +174,7 @@ class _PallasEngine(Engine):
         return StackedSlotPool(
             self, n_vars, dom_size, capacity,
             self._empty_tables(dims, capacity),
-            encode=lambda csp: self._prepare_net(csp)[0],
+            install=slot_install_program(ops.encode_network, self.kind, dims[0], dims[1]),
             dispatch=dispatch,
         )
 

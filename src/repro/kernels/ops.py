@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from repro import faults, obs
 from repro.core import rtac
 from repro.core.csp import CSP
-from repro.core.engine import pad_dom, pad_network, padded_shape
+from repro.core.engine import pad_dom, pad_pairs, padded_shape
 from . import autotune, ref, rtac_support
 
 Array = jax.Array
@@ -106,6 +106,17 @@ def encode_cons(kind: str, cons: Array) -> Array:
     return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def encode_network(cons, mask, kind: str, n_p: int, d_p: int):
+    """A network as its CSP holds it — (n, n, d, d) bool cons and (n, n) mask,
+    host or device — padded to kernel shape (n_p, d_p) (`pad_pairs`, the §2
+    contract) and laid out as the kernel's pair (`encode_cons`, u8 mask), in
+    one compiled program per shape. A slot install traces it into its donated
+    write (`core.engine.slot_install_program`)."""
+    cons, mask = pad_pairs(cons, mask, n_p, d_p)
+    return encode_cons(kind, cons), mask.astype(jnp.uint8)
+
+
 def prepare_network(kind: str, csp: CSP, n_block: int = 8):
     """-> (network, dom_padded, dims). network = (cons, mask u8 (n_p, n_p)).
 
@@ -113,8 +124,8 @@ def prepare_network(kind: str, csp: CSP, n_block: int = 8):
     faults.inject("kernel.launch", kernel=kind)
 
     def build():
-        cons, mask, n_p, d_p = pad_network(csp, n_block, D_MULT)
-        network = (encode_cons(kind, cons), mask.astype(jnp.uint8))
+        n_p, d_p = padded_shape(*csp.dom.shape, n_block, D_MULT)
+        network = encode_network(csp.cons, csp.mask, kind, n_p, d_p)
         return network, kernel_dims(kind, n_p, d_p, n_block)
 
     network, dims = _cached(kind, csp, n_block, build)

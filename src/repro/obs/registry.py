@@ -4,9 +4,10 @@ Where the tracer (`obs.tracing`) answers "where did the wall-clock go",
 the registry answers "how much of everything happened": every subsystem
 publishes into ONE process-wide table under dotted names
 (``driver.rounds``, ``cache.hits``, ``speculation.split_granted``,
-``kernels.fn_builds``, ...) and `snapshot()` reduces it to one JSON-ready
-dict with the stable schema ``repro-obs/v1`` that the benchmarks, the
-tracker history, and the CLI all consume.
+``kernels.fn_builds``, ``slots.installed``, ``slots.install_h2d_bytes``
+— the network bytes slot installs upload, ...) and `snapshot()` reduces it
+to one JSON-ready dict with the stable schema ``repro-obs/v1`` that the
+benchmarks, the tracker history, and the CLI all consume.
 
 The robustness fabric (DESIGN.md §12) publishes here too:
 ``faults.injected`` / ``faults.injected.<site>`` (fired injections),

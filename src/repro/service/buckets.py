@@ -19,11 +19,9 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.numpy as jnp
-
 from repro import obs
 from repro.core.csp import CSP
-from repro.core.engine import next_pow2, pad_dom
+from repro.core.engine import next_pow2, pad_csp_to
 
 
 @dataclasses.dataclass(frozen=True, order=True)
@@ -102,15 +100,11 @@ def speculative_budget(
 
 def pad_csp(csp: CSP, bucket: Bucket) -> CSP:
     """Pad a CSP into its bucket shape under the §2 contract. The AC closure
-    and the MAC search restricted to the original (n, d) slice are unchanged."""
+    and the MAC search restricted to the original (n, d) slice are unchanged.
+    A CSP of host (numpy) arrays is padded on the host and comes back as
+    numpy: admission moves nothing to the device, and a cache miss uploads
+    its network once, in the slot install."""
     n, d = csp.dom.shape
     if not bucket.contains(n, d):
         raise ValueError(f"csp shape ({n}, {d}) does not fit bucket {bucket}")
-    dn, dd = bucket.n_p - n, bucket.d_p - d
-    if dn == 0 and dd == 0:
-        return csp
-    return CSP(
-        cons=jnp.pad(csp.cons, ((0, dn), (0, dn), (0, dd), (0, dd))),
-        mask=jnp.pad(csp.mask, ((0, dn), (0, dn))),
-        dom=pad_dom(jnp.asarray(csp.dom), bucket.n_p, bucket.d_p),
-    )
+    return pad_csp_to(csp, bucket.n_p, bucket.d_p)

@@ -651,12 +651,15 @@ class SolverService:
         level = max(req.engine_level, self._bucket_floor.get(req.bucket, 0))
         req.engine_level = level
         rt = self._runtime(req.bucket, level)
+        # the search's bucket-shaped CSP, padded on the host for host arrays
         padded = pad_csp(req.csp, req.bucket)
 
         def install() -> int:
             slot = rt.take_slot()
             try:
-                rt.pool.install(slot, padded)
+                # the request's own network: its one upload is the smallest,
+                # and a stacked pool pads it inside its install program
+                rt.pool.install(slot, req.csp)
             except BaseException:
                 # the pool registered nothing (its slot entry is only set on
                 # success) — just return the slot to the free list

@@ -232,7 +232,7 @@ _OBS_COUNTERS = (
     "driver.cancelled_members",
     "speculation.denied", "speculation.split_granted",
     "speculation.portfolio_granted", "speculation.clamped",
-    "cache.hits", "cache.misses",
+    "cache.hits", "cache.misses", "slots.installed", "slots.install_h2d_bytes",
 )
 _OBS_HISTS = (
     "many.rounds_per_instance", "many.launches_per_solve",

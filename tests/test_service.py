@@ -7,12 +7,15 @@ joining and leaving rounds mid-flight — returns solutions AND per-instance
 search statistics bit-identical to running `mac_solve` on each CSP alone.
 """
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro.core.engine as engine_mod
 from repro.core import check_solution, mac_solve
+from repro.core.csp import CSP
 from repro.engines import get_engine
+from repro.kernels import ops
 from repro.problems import generate, generate_batch
 from repro.service import (
     Bucket,
@@ -365,6 +368,97 @@ def test_pad_csp_preserves_search_semantics():
     assert not np.asarray(padded.mask)[n:, :].any()  # padded vars unconstrained
     with pytest.raises(ValueError, match="does not fit"):
         pad_csp(csp, Bucket(4, 4))
+
+
+def _host_csp(csp):
+    """The CSP as a client holds it: host (numpy) arrays."""
+    return CSP(*(np.asarray(a) for a in csp))
+
+
+def test_pad_csp_pads_host_arrays_on_the_host():
+    """For numpy input `pad_csp` returns numpy arrays, bit-identical to the
+    jax.numpy padding of the same CSP."""
+    csp = generate("model_rb", n=10, hardness=1.0, seed=2)
+    b = bucket_for(*csp.dom.shape)
+    on_device = pad_csp(csp, b)
+    on_host = pad_csp(_host_csp(csp), b)
+    for got, want in zip(on_host, on_device):
+        assert isinstance(got, np.ndarray)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got, np.asarray(want))
+
+
+@pytest.mark.parametrize(
+    "engine, n, d",
+    [
+        pytest.param("pallas_packed", 50, 23, marks=pytest.mark.pallas, id="packed-W1"),
+        pytest.param("pallas_packed", 40, 40, marks=pytest.mark.pallas, id="packed-W2"),
+        pytest.param("pallas_dense", 12, 6, marks=pytest.mark.pallas, id="dense"),
+        pytest.param("einsum", 10, 6, id="einsum"),
+    ],
+)
+def test_slot_install_writes_the_encoded_network_and_nothing_else(engine, n, d):
+    """One install writes `ops.encode_cons` of the padded network, and the
+    mask cast to the table's dtype, into its slot, bit for bit; every other
+    slot keeps its bytes."""
+    b = bucket_for(n, d)
+    csps = [
+        _host_csp(generate("random_binary", n=n, d=d, density=0.3,
+                           tightness=0.4, seed=s))
+        for s in range(3)
+    ]
+    eng = get_engine(engine)
+    pool = eng.open_slot_pool(b.n_p, b.d_p, 3)
+    pool.install(0, pad_csp(csps[0], b))  # a bucket-shaped network fits too
+    pool.install(2, csps[2])
+    before = [np.asarray(t) for t in pool.tables]
+    pool.install(1, csps[1])  # the request's own network, as admission hands it
+    after = [np.asarray(t) for t in pool.tables]
+
+    kind = getattr(eng, "kind", None)
+    n_p, d_p = ops.kernel_dims(kind, b.n_p, b.d_p)[:2] if kind else (b.n_p, b.d_p)
+    cons = jnp.pad(jnp.asarray(csps[1].cons),
+                   ((0, n_p - n), (0, n_p - n), (0, d_p - d), (0, d_p - d)))
+    mask = jnp.pad(jnp.asarray(csps[1].mask), ((0, n_p - n), (0, n_p - n)))
+    want = (ops.encode_cons(kind, cons), mask.astype(jnp.uint8)) if kind else (cons, mask)
+    if kind == "packed":
+        assert after[0].shape[1] == -(-d_p // 32)  # W words per domain
+    for got, old, row in zip(after, before, want):
+        assert got.dtype == old.dtype
+        np.testing.assert_array_equal(got[1], np.asarray(row))
+        np.testing.assert_array_equal(got[[0, 2]], old[[0, 2]])
+
+
+def test_cache_hit_uploads_no_network():
+    """A miss uploads its network once, counted by `slots.install_h2d_bytes`;
+    admitting a request whose network is resident adds nothing to it or to
+    `slots.installed`, and moves no network bytes to the device (its
+    admission passes the transfer guard: only the explicit root upload)."""
+    import jax
+
+    from repro import obs
+
+    csp = _host_csp(generate("model_rb", n=10, hardness=1.0, seed=2))
+    assert bucket_for(*csp.dom.shape) != Bucket(*csp.dom.shape)  # padded
+    svc = SolverService(engine="einsum")
+    uploaded = obs.REGISTRY.counter("slots.install_h2d_bytes")
+    installed = obs.REGISTRY.counter("slots.installed")
+    first = svc.submit(csp)
+    svc.step()
+    assert (obs.REGISTRY.counter("slots.install_h2d_bytes") - uploaded
+            == csp.cons.nbytes + csp.mask.nbytes)
+    assert obs.REGISTRY.counter("slots.installed") - installed == 1
+
+    uploaded = obs.REGISTRY.counter("slots.install_h2d_bytes")
+    second = svc.submit(csp)
+    with jax.transfer_guard("disallow"):
+        svc.step()
+    assert svc.cache.hits == 1 and svc.cache.misses == 1
+    assert obs.REGISTRY.counter("slots.install_h2d_bytes") == uploaded
+    assert obs.REGISTRY.counter("slots.installed") - installed == 1
+    svc.run_until_idle()
+    _assert_matches_sequential(first, csp)
+    _assert_matches_sequential(second, csp)
 
 
 def test_requests_route_to_distinct_buckets():
