@@ -534,6 +534,10 @@ def _frontier_step(buf, abuf, networks, parent, var, val, dest, net_idx, *, fix,
         ).astype(jnp.int32)
         arow = jnp.take_along_axis(res.dom, avar[:, None, None], axis=1)[:, 0, :]
         out = out + (avar, arow)
+    if res.revised is not None:
+        # a fixpoint that streamed its network: per-row work counts, shipped
+        # with the round's metadata
+        out = out + (res.revised, res.streamed)
     return out
 
 
@@ -563,19 +567,28 @@ class _PendingFrontierRound:
     still device futures (JAX async dispatch); ``resolve()`` fetches them —
     the round's only device→host transfer — and frees inconsistent rows."""
 
-    def __init__(self, table: "FrontierTable", meta, dest: List[int], keys: List[Any], r: int):
+    def __init__(self, table: "FrontierTable", meta, dest: List[int], keys: List[Any], r: int,
+                 alt: bool = False):
         self._table = table
         self._meta = meta
         self._dest = dest
         self._keys = keys
         self._r = r
+        self._alt = alt  # whether the anti-MRV pair follows the MRV pair
 
     def resolve(self) -> RoundMeta:
         # the host's one wait on the device in a round
         with obs.span("round.wait", cat="driver"):
-            cons, k, bvar, vrow, *alt = jax.device_get(self._meta)
-        self._table._count_d2h(cons, k, bvar, vrow, *alt)
+            cons, k, bvar, vrow, *rest = jax.device_get(self._meta)
+        self._table._count_d2h(cons, k, bvar, vrow, *rest)
         r = self._r
+        alt, counts = (rest[:2], rest[2:]) if self._alt else ((), rest)
+        if counts:  # the round's fixpoint streamed its networks from HBM
+            revised, streamed = counts
+            obs.REGISTRY.counter_add("kernel.revised_vars", int(np.sum(revised[:r])))
+            obs.REGISTRY.counter_add(
+                "kernel.stream_bytes", int(np.sum(streamed[:r], dtype=np.float64))
+            )
         handles: List[Optional[int]] = []
         for i, (key, row) in enumerate(zip(self._keys, self._dest)):
             if bool(cons[i]):
@@ -841,7 +854,8 @@ class FrontierTable:
             obs.fence(meta)
         obs.REGISTRY.gauge_set("frontier.rows_live", self.rows_live)
         obs.REGISTRY.gauge_set("frontier.capacity", self.capacity)
-        return _PendingFrontierRound(self, tuple(meta), dest, [s.key for s in specs], r)
+        return _PendingFrontierRound(self, tuple(meta), dest, [s.key for s in specs], r,
+                                     alt=self._want_alt)
 
 
 def frontier_capacity(n_searches: int, n_vars: int, dom_size: int,

@@ -59,6 +59,11 @@ class EnforceResult(NamedTuple):
     dom: Array  # (n, d) bool — the AC closure D_ac (valid only if consistent)
     consistent: Array  # () bool — False iff some domain wiped out
     n_recurrences: Array  # () int32 — K of Eq. 1 (Table 1 "#Recurrence")
+    #: from a fixpoint that streams its network from HBM only (else None):
+    #: variables seeded for revision, summed over the recurrences (int32),
+    #: and the network and mask bytes it read (float32)
+    revised: Optional[Array] = None
+    streamed: Optional[Array] = None
 
 
 class _State(NamedTuple):

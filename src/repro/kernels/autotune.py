@@ -277,7 +277,10 @@ def ensure_tuned(kind: str, n_p: int, d_p: int, r: int, **tune_kwargs) -> TuneCo
 def maybe_tune(kind: str, n_p: int, d_p: int, r: int) -> Optional[TuneConfig]:
     """Engine hook: tune-on-first-use, gated by ``REPRO_AUTOTUNE=1`` (timing
     a bucket in interpret mode is not free, so it is opt-in)."""
-    if not os.environ.get(TUNE_ENV):
+    from repro.kernels import rtac_support
+
+    # where no row fits VMEM the launch streams one row a cell: no block_r
+    if not os.environ.get(TUNE_ENV) or rtac_support.max_block_r(kind, n_p, d_p) == 0:
         return None
     return ensure_tuned(kind, n_p, d_p, r)
 

@@ -27,6 +27,7 @@ import weakref
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import faults, obs
 from repro.core import rtac
@@ -147,6 +148,58 @@ def kernel_operands(net_g, doms, changed):
     return cons_g, dom, seed, mask
 
 
+def stream_mask_tiles(mask_tables, d_p: int, ty: int):
+    """Stacked (B, n_p, n_p) u8 masks -> the streamed kernel's (B, T, ty, N)
+    int8 mask tiles: tile t holds mask[y, x·d + a] = constrained(x, y) for
+    its variables y (`rtac_support.stream_tile_starts`), whole, so each tile
+    is one aligned DMA."""
+    n_p = mask_tables.shape[1]
+    starts = np.asarray(rtac_support.stream_tile_starts(n_p, ty))
+    rows = starts[:, None] + np.arange(ty)  # (T, ty)
+    return jnp.repeat(jnp.swapaxes(mask_tables, 1, 2)[:, rows], d_p, axis=3).astype(jnp.int8)
+
+
+def _fixpoint_stream(kind: str, interpret: bool, networks, doms, changed, idx):
+    """The fused fixpoint at a shape no row of which fits VMEM: the stacked
+    tables and the row→network index go to the kernel as they are (no
+    per-row gather), and the network streams from HBM. A row equal to the
+    row before it (a round padded to its width repeats its last row) is not
+    run: it takes that row's result, and reads and revises nothing."""
+    r, n_p, d_p = doms.shape
+    ty = rtac_support.stream_ty(n_p, d_p)
+    if kind != "packed" or not ty:
+        raise ValueError(
+            f"{kind} fixpoint at kernel shape (n={n_p}, d={d_p}): no row fits the "
+            f"{rtac_support.VMEM_BUDGET / 2**20:.0f} MiB VMEM budget, and only the "
+            "packed encoding streams its network from HBM"
+        )
+    cons, mask = networks
+    idx = idx.astype(jnp.int32)
+    ok = jnp.all(jnp.any(doms, axis=-1), axis=-1)  # (R,) no domain empty
+    same = (idx[1:] == idx[:-1]) & jnp.all(doms[1:] == doms[:-1], axis=(1, 2)) & jnp.all(
+        changed[1:] == changed[:-1], axis=1)
+    same = jnp.concatenate([jnp.zeros((1,), jnp.bool_), same])
+    src = jax.lax.cummax(jnp.where(same, 0, jnp.arange(r)))  # the row each one copies
+    seed = changed & (ok & ~same)[:, None]
+    dom, ok, k, revised, tiles = rtac_support.fixpoint_rows_stream(
+        cons, stream_mask_tiles(mask, d_p, ty), idx,
+        doms.astype(jnp.int32).reshape(r, 1, n_p * d_p),
+        seed.astype(jnp.int32).reshape(r, n_p, 1),
+        ok.astype(jnp.int32).reshape(r, 1, 1),
+        d=d_p, ty=ty, interpret=interpret,
+    )
+    tile_bytes = rtac_support.stream_tile_bytes(n_p, d_p, ty)
+    return rtac.EnforceResult(
+        dom[src].reshape(r, n_p, d_p).astype(jnp.bool_),
+        ok[src, 0, 0].astype(jnp.bool_),
+        k[src, 0, 0],
+        revised[:, 0, 0],
+        # float32: a row's bytes can pass 2^31; a multiple of the tile's
+        # bytes is exact far past any fixpoint's tile count
+        tiles[:, 0, 0].astype(jnp.float32) * tile_bytes,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Closure factories
 # ---------------------------------------------------------------------------
@@ -187,14 +240,23 @@ def revise_fn(kind: str, interpret: bool):
 
 @functools.lru_cache(maxsize=None)
 def fixpoint_rows_fn(kind: str, interpret: bool):
-    """Stacked one-launch fixpoint. Same signature as
-    `rtac.enforce_rows_generic` minus the gather (net_g, dom_p, ch_p ->
-    EnforceResult in padded coordinates), so engines swap it for the stepped
-    path wholesale."""
+    """Stacked one-launch fixpoint: (networks, dom_p, ch_p, idx) ->
+    EnforceResult in padded coordinates, row i against ``networks[idx[i]]``
+    of the stacked tables — the signature of `rtac.enforce_rows_generic`, so
+    engines swap it for the stepped path wholesale.
+
+    Chosen by shape alone: where one row's network fits VMEM
+    (`rtac_support.max_block_r` > 0) each row's network is gathered and the
+    in-VMEM kernel runs; where none does, the stacked tables stream from HBM
+    (`_fixpoint_stream`), and the result also carries each row's revised
+    variables and streamed bytes."""
     _count_build(f"{kind}_fixpoint_rows")
 
-    def fixpoint_rows(net_g, doms, changed):
+    def fixpoint_rows(networks, doms, changed, idx):
         r, n_p, d_p = doms.shape
+        if rtac_support.max_block_r(kind, n_p, d_p) == 0:
+            return _fixpoint_stream(kind, interpret, networks, doms, changed, idx)
+        net_g = jax.tree_util.tree_map(lambda t: t[idx], networks)
         block_r = autotune.fused_block_r(kind, n_p, d_p, r)
         dom, ok, k = rtac_support.fixpoint_rows(
             *kernel_operands(net_g, doms, changed),
@@ -211,12 +273,11 @@ def fixpoint_rows_fn(kind: str, interpret: bool):
 
 @functools.partial(jax.jit, static_argnames=("fixpoint_rows_fn",))
 def enforce_rows_fused(networks, dom, changed0, instance_idx, fixpoint_rows_fn):
-    """Fused-kernel counterpart of `rtac.enforce_rows_generic`: gather each
-    row's network from the stacked tables, then ONE kernel launch runs the
-    whole recurrence. Inputs/outputs match `enforce_rows_generic` exactly so
+    """Fused-kernel counterpart of `rtac.enforce_rows_generic`: each row
+    against its own network of the stacked tables, in ONE kernel launch of
+    the whole recurrence. Inputs/outputs match `enforce_rows_generic` so
     `engines.pallas` routes between them with a flag."""
-    net_g = jax.tree_util.tree_map(lambda t: t[instance_idx], networks)
-    return fixpoint_rows_fn(net_g, dom, changed0)
+    return fixpoint_rows_fn(networks, dom, changed0, instance_idx)
 
 
 # ---------------------------------------------------------------------------
@@ -249,12 +310,11 @@ def frontier_fn(kind: str, fused: bool, interpret: bool):
         dom_p = rtac_support.assign_padded_rows(pad_dom(doms, n_p, d_p), var, val)
         ch_p = _padded_seed(var, n, n_p)
         if fused:
-            net_rows = jax.tree_util.tree_map(lambda t: t[idx], net_g)
-            res = fixpoint_rows_fn(kind, interpret)(net_rows, dom_p, ch_p)
+            res = fixpoint_rows_fn(kind, interpret)(net_g, dom_p, ch_p, idx)
         else:
             res = rtac.enforce_rows_generic(
                 net_g, dom_p, ch_p, idx, revise_rows_fn=rows_fn(kind, interpret)
             )
-        return rtac.EnforceResult(res.dom[:, :n, :d], res.consistent, res.n_recurrences)
+        return res._replace(dom=res.dom[:, :n, :d])
 
     return assign_enforce_rows

@@ -5,7 +5,9 @@ the registry answers "how much of everything happened": every subsystem
 publishes into ONE process-wide table under dotted names
 (``driver.rounds``, ``cache.hits``, ``speculation.split_granted``,
 ``kernels.fn_builds``, ``slots.installed``, ``slots.install_h2d_bytes``
-— the network bytes slot installs upload, ...) and `snapshot()` reduces it
+— the network bytes slot installs upload, ``kernel.revised_vars`` and
+``kernel.stream_bytes`` — the variables a fixpoint that streams its network
+from HBM revised and the bytes it read, ...) and `snapshot()` reduces it
 to one JSON-ready dict with the stable schema ``repro-obs/v1`` that the
 benchmarks, the tracker history, and the CLI all consume.
 

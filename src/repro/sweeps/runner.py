@@ -233,6 +233,7 @@ _OBS_COUNTERS = (
     "speculation.denied", "speculation.split_granted",
     "speculation.portfolio_granted", "speculation.clamped",
     "cache.hits", "cache.misses", "slots.installed", "slots.install_h2d_bytes",
+    "kernel.revised_vars", "kernel.stream_bytes",
 )
 _OBS_HISTS = (
     "many.rounds_per_instance", "many.launches_per_solve",

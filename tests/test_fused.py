@@ -16,6 +16,7 @@ run in CI's dedicated pallas leg.
 
 import json
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -116,9 +117,7 @@ def test_fused_rows_fn_matches_ref_oracle_chain(n, d, brx, bry):
     r = len(idx)
     dom_p = pad_dom(jnp.asarray(doms), n_p, d_p)
     ch_p = pad_changed(jnp.asarray(changed), n, n_p, batch=(r,))
-    fused = ops.fixpoint_rows_fn("packed", True)(
-        (tables[0][idx], tables[1][idx]), dom_p, ch_p
-    )
+    fused = ops.fixpoint_rows_fn("packed", True)(tables, dom_p, ch_p, jnp.asarray(idx))
     for row, j in enumerate(idx):
         dom = jnp.asarray(doms[row])
         ch = jnp.asarray(changed[row])
@@ -241,3 +240,136 @@ def test_autotune_sanitizes_stale_tiles_and_block_r():
     assert autotune.effective_block_r(8, 5) == 5
     assert autotune.effective_block_r(4, 6) == 3
     assert autotune.effective_block_r(8, 8) == 8
+
+
+# --- the streamed fixpoint (networks larger than VMEM) -----------------------
+
+#: (case, n_vars, dom_size, ty): W=1 and W=2; 24 variables in tiles of 16
+#: (the last tile pulled back to overlap the first) and of 8; d_p 8 and 40
+#: settle each tile over all lanes, d_p 16 and 64 over its own lane window
+STREAM_CASES = [
+    ("w1_ragged_last_tile", 20, 6, 16),
+    ("w1_windowed_ragged", 20, 11, 16),
+    ("w2_three_tiles", 24, 33, 8),
+    ("w2_windowed", 12, 64, 8),
+]
+
+
+def _stream_reference(csp, dom, ch, starts, ty):
+    """Host chain of `revise_ref` sweeps: (dom, consistent, k, revised,
+    tiles read), where a sweep reads each tile holding a seeded variable."""
+    dom, ch = jnp.asarray(dom), jnp.asarray(ch)
+    k = revised = tiles = 0
+    if not bool(jnp.all(jnp.any(dom, axis=-1))):
+        return dom, False, 0, 0, 0
+    while bool(jnp.any(ch)):
+        seeded = np.asarray(ch)
+        revised += int(seeded.sum())
+        tiles += sum(bool(seeded[s:s + ty].any()) for s in starts)
+        new_dom = dom & ~revise_ref(csp.cons, csp.mask, dom, ch)
+        ch = jnp.any(new_dom != dom, axis=-1)
+        dom = new_dom
+        k += 1
+        if not bool(jnp.all(jnp.any(dom, axis=-1))):
+            return dom, False, k, revised, tiles
+    return dom, True, k, revised, tiles
+
+
+@pytest.mark.parametrize("case,n,d,ty", STREAM_CASES, ids=[c[0] for c in STREAM_CASES])
+def test_streamed_fixpoint_bit_identical_to_in_vmem_and_stepped(case, n, d, ty, monkeypatch):
+    """The streamed launch, run at a small shape by a small VMEM budget
+    handed to the plan, against the in-VMEM kernel, the stepped oracle and
+    a host chain of `revise_ref` sweeps: rows of three networks through the
+    index, root rows, a sparse seed, one seeded variable, a row wiped out at
+    the start and a row with no seed (both inactive). Through the dispatch
+    (`ops.fixpoint_rows_fn`, under that budget), rows repeated at the end of
+    the round, as padding repeats them, take their row's result and read
+    nothing."""
+    csps = [random_csp(n, d, 0.7, 0.5, seed=90 + i) for i in range(3)]
+    prepared = [ops.prepare_packed(c) for c in csps]
+    n_p, d_p, w = prepared[0][2]
+    assert w == (2 if d > 32 else 1)
+    budget = rtac_support.stream_vmem_bytes(n_p, d_p, ty)
+    assert rtac_support.max_block_r("packed", n_p, d_p, budget) == 0
+    assert rtac_support.stream_ty(n_p, d_p, budget) == ty
+    starts = rtac_support.stream_tile_starts(n_p, ty)
+    assert (n_p % ty != 0) == (starts[-1] % ty != 0)
+    tables = (jnp.stack([p[0][0] for p in prepared]), jnp.stack([p[0][1] for p in prepared]))
+    idx = np.array([0, 1, 2, 1, 0, 2], np.int32)
+    doms = np.stack([np.asarray(csps[j].dom) for j in idx])
+    doms[3, 0, 1:] = False
+    doms[4, 2, :] = False  # wiped out before the first sweep
+    changed = np.ones((len(idx), n), dtype=bool)  # rows 0, 3, 4: roots
+    changed[1] = np.random.default_rng(n + d).random(n) < 0.5
+    changed[2] = np.arange(n) == n - 1  # one variable, in the last tile
+    changed[5] = False
+    r = len(idx)
+    dom_p = pad_dom(jnp.asarray(doms), n_p, d_p)
+    ch_p = pad_changed(jnp.asarray(changed), n, n_p, batch=(r,))
+    operands = ops.kernel_operands((tables[0][idx], tables[1][idx]), dom_p, ch_p)
+    in_vmem = rtac_support.fixpoint_rows(*operands, encoding="packed", d=d_p, block_r=1,
+                                         interpret=True)
+    ok = np.asarray(jnp.all(jnp.any(dom_p, axis=-1), axis=-1)).astype(np.int32)
+    got = rtac_support.fixpoint_rows_stream(
+        tables[0], ops.stream_mask_tiles(tables[1], d_p, ty), jnp.asarray(idx),
+        operands[1], operands[2] * ok[:, None, None], ok.reshape(r, 1, 1),
+        d=d_p, ty=ty, interpret=True,
+    )
+    for a, b in zip(in_vmem, got[:3]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    stepped = _stepped_oracle(tables, None, idx, dom_p, ch_p, ops.rows_fn("packed", True))
+    np.testing.assert_array_equal(np.asarray(got[0]).reshape(r, n_p, d_p).astype(bool),
+                                  np.asarray(stepped.dom))
+    np.testing.assert_array_equal(np.asarray(got[1])[:, 0, 0].astype(bool),
+                                  np.asarray(stepped.consistent))
+    np.testing.assert_array_equal(np.asarray(got[2])[:, 0, 0], np.asarray(stepped.n_recurrences))
+    revised, tiles = np.asarray(got[3])[:, 0, 0], np.asarray(got[4])[:, 0, 0]
+    for row, j in enumerate(idx):
+        dom, ok, k, rev, nt = _stream_reference(
+            csps[j], doms[row], changed[row], starts, ty)
+        assert (bool(np.asarray(got[1])[row, 0, 0]), int(np.asarray(got[2])[row, 0, 0])) == (ok, k)
+        assert (int(revised[row]), int(tiles[row])) == (rev, nt), row
+        if ok:
+            np.testing.assert_array_equal(
+                np.asarray(got[0])[row, 0].reshape(n_p, d_p)[:n, :d].astype(bool), np.asarray(dom))
+    assert revised[4] == revised[5] == tiles[4] == tiles[5] == 0  # the inactive rows
+    assert revised[0] >= n and tiles[0] >= len(starts)  # a root revises every variable
+
+    monkeypatch.setattr(rtac_support, "VMEM_BUDGET", budget)
+    padded = np.concatenate([np.arange(r), [r - 1, r - 1]])
+    res = ops.fixpoint_rows_fn("packed", True)(tables, dom_p[padded], ch_p[padded], idx[padded])
+    np.testing.assert_array_equal(np.asarray(res.dom), np.asarray(stepped.dom)[padded])
+    np.testing.assert_array_equal(np.asarray(res.consistent), np.asarray(stepped.consistent)[padded])
+    np.testing.assert_array_equal(np.asarray(res.n_recurrences),
+                                  np.asarray(stepped.n_recurrences)[padded])
+    np.testing.assert_array_equal(np.asarray(res.revised), np.r_[revised, 0, 0])
+    tile_bytes = rtac_support.stream_tile_bytes(n_p, d_p, ty)
+    np.testing.assert_array_equal(np.asarray(res.streamed), np.r_[tiles, 0, 0] * tile_bytes)
+
+
+def test_fused_dispatch_streams_only_where_no_row_fits_vmem(monkeypatch):
+    """`fixpoint_rows_fn` picks the streamed launch by shape alone: at the
+    frb cells' kernel shapes today's kernel (after its per-row gather), at
+    (600, 32) and under a budget no row fits, the streamed one, handed the
+    stacked tables; the result then carries revised and streamed."""
+
+    def launched(n_p, d_p, w, rows=8, b=3):
+        fix = ops.fixpoint_rows_fn("packed", True)
+        nets = (jax.ShapeDtypeStruct((b, w, n_p, n_p * d_p), jnp.int32),
+                jax.ShapeDtypeStruct((b, n_p, n_p), jnp.uint8))
+        args = (jax.ShapeDtypeStruct((rows, n_p, d_p), jnp.bool_),
+                jax.ShapeDtypeStruct((rows, n_p), jnp.bool_),
+                jax.ShapeDtypeStruct((rows,), jnp.int32))
+        out = jax.eval_shape(fix, nets, *args)
+        text = str(jax.make_jaxpr(fix)(nets, *args))
+        return ("rtac_fixpoint_packed_stream" in text, "rtac_fixpoint_packed" in text,
+                out.revised is not None)
+
+    for n_p, d_p, w in [(64, 32, 1), (104, 40, 2)]:  # frb50's bucket, frb100
+        assert rtac_support.max_block_r("packed", n_p, d_p) > 0
+        assert launched(n_p, d_p, w) == (False, True, False)
+    assert rtac_support.max_block_r("packed", 600, 32) == 0
+    assert rtac_support.stream_ty(600, 32) == 64
+    assert launched(600, 32, 1) == (True, True, True)
+    monkeypatch.setattr(rtac_support, "VMEM_BUDGET", rtac_support.stream_vmem_bytes(24, 8, 8))
+    assert launched(24, 8, 1) == (True, True, True)

@@ -158,3 +158,27 @@ def test_sharded_enforcer_compiles_on_four_chips(topo, impl):
     text = compiled.as_text()
     assert "all-gather" in text
     assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
+def test_frontier_step_streaming_its_networks_compiles_for_v5e(one_chip):
+    # solve_many's round program at rb600-batch8's width: 8 instances of
+    # (600, 32), whose 57.6 MB a row cannot sit in VMEM, so the fused
+    # fixpoint streams each network from HBM
+    n, d, b, rows, cap = 600, 32, 8, 128, 8192
+    n_p, d_p, w = ops.kernel_dims("packed", n, d)
+    assert rtac_support.max_block_r("packed", n_p, d_p) == 0
+    nets = (
+        _sds((b, w, n_p, n_p * d_p), jnp.int32, one_chip),
+        _sds((b, n_p, n_p), jnp.uint8, one_chip),
+    )
+    idx = _sds((rows,), jnp.int32, one_chip)
+    step = functools.partial(
+        _frontier_step.__wrapped__, fix=ops.frontier_fn("packed", True, False)
+    )
+    compiled = _compile(
+        step,
+        _sds((cap, n, d), jnp.bool_, one_chip),
+        _sds((cap, n), jnp.bool_, one_chip),
+        nets, idx, idx, idx, idx, idx,
+    )
+    assert "rtac_fixpoint_packed_stream" in compiled.as_text()
